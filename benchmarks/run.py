@@ -13,6 +13,7 @@ from benchmarks import (dual_input, fig4_vehicle_n2, fig5_vehicle_n270,
                         fig6_ssd_n2, latency_breakdown, roofline,
                         serving_bench)
 from benchmarks.common import HEADER, emit
+from repro.launch.compile_cache import place_compile_cache
 
 BENCHES = {
     "fig4": fig4_vehicle_n2,
@@ -26,6 +27,7 @@ BENCHES = {
 
 
 def main() -> None:
+    place_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, choices=list(BENCHES))
     args = ap.parse_args()

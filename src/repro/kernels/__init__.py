@@ -1,3 +1,13 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels: each package holds ``kernel.py`` (the
+``pallas_call``), ``ops.py`` (the jitted public wrapper) and ``ref.py``
+(a pure-jnp oracle)."""
+from __future__ import annotations
+
+import jax
+
+
+def interpret_mode() -> bool:
+    """Run kernel bodies in the Pallas interpreter only on the CPU
+    backend (tests); every accelerator backend gets the compiled
+    kernel."""
+    return jax.default_backend() == "cpu"

@@ -6,21 +6,18 @@ from functools import partial
 
 import jax
 
+from repro.kernels import interpret_mode
 from repro.kernels.decode_attention.kernel import (
     decode_attention_pallas, paged_decode_attention_pallas)
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 @partial(jax.jit, static_argnames=("bk",))
 def decode_attention(q, k_cache, v_cache, lengths, *, bk: int = 512):
     return decode_attention_pallas(q, k_cache, v_cache, lengths, bk=bk,
-                                   interpret=_on_cpu())
+                                   interpret=interpret_mode())
 
 
 @jax.jit
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths):
     return paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
-                                         lengths, interpret=_on_cpu())
+                                         lengths, interpret=interpret_mode())

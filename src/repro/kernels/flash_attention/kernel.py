@@ -8,9 +8,13 @@ across kv iterations without HBM round-trips. Block shapes are chosen so
 one (g*bq, d) q-tile, one (bk, d) kv-tile and the (g*bq, bk) score tile
 fit VMEM together, with the matmul dims aligned to the 128-lane MXU.
 
-GQA layout: q is passed as (B, Hk, g, Sq, D) — all g query heads of one
-kv head share a grid step, so k/v tiles are loaded once per group (g x
-bandwidth saving vs. per-q-head grids, the reason GQA exists).
+GQA layout: q is passed as (B, Hk, g, Sq, D) and k/v as (B, Hk, Sk, D)
+— all g query heads of one kv head share a grid step, so k/v tiles are
+loaded once per group (g x bandwidth saving vs. per-q-head grids, the
+reason GQA exists). Head-major k/v make every block's last two dims
+(bk, D), which the TPU's (8, 128) tiling accepts for any head count; the
+wrapper pays one transpose of k/v for it, small next to the O(S^2)
+attention.
 """
 from __future__ import annotations
 
@@ -52,8 +56,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0].reshape(-1, q_ref.shape[-1])     # (g*bq, d)
-        k = k_ref[0, :, 0, :]                            # (bk, d)
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0, 0]                                  # (bk, d)
+        v = v_ref[0, 0]
         s = jax.lax.dot_general(
             q.astype(jnp.float32), k.astype(jnp.float32),
             (((1,), (1,)), ((), ())),
@@ -68,19 +72,19 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         if window > 0:
             mask &= qpos - kpos < window
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                              # (g*bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, 0] = out.reshape(o_ref.shape[2], o_ref.shape[3],
                                   o_ref.shape[4]).astype(o_ref.dtype)
 
@@ -102,30 +106,31 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     # (B, Hk, g, Sq, D) so one grid step covers all g q-heads of a kv head
     qg = q.reshape(b, sq, hk, g, d).transpose(0, 2, 3, 1, 4)
+    kh = k.transpose(0, 2, 1, 3)                         # (B, Hk, Sk, D)
+    vh = v.transpose(0, 2, 1, 3)
 
     grid = (b * hk, nq, nk)
     kernel = functools.partial(_fa_kernel, scale=scale, causal=causal,
                                window=window, bq=bq, bk=bk, nk=nk)
+    kv_spec = pl.BlockSpec((1, 1, bk, d),
+                           lambda bh, iq, ik: (bh // hk, bh % hk, ik, 0))
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, g, bq, d),
                          lambda bh, iq, ik: (bh // hk, bh % hk, 0, iq, 0)),
-            pl.BlockSpec((1, bk, 1, d),
-                         lambda bh, iq, ik: (bh // hk, ik, bh % hk, 0)),
-            pl.BlockSpec((1, bk, 1, d),
-                         lambda bh, iq, ik: (bh // hk, ik, bh % hk, 0)),
+            kv_spec, kv_spec,
         ],
         out_specs=pl.BlockSpec(
             (1, 1, g, bq, d),
             lambda bh, iq, ik: (bh // hk, bh % hk, 0, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hk, g, sq, d), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((g * bq,), jnp.float32),
-            pltpu.VMEM((g * bq,), jnp.float32),
+            pltpu.VMEM((g * bq, 1), jnp.float32),
+            pltpu.VMEM((g * bq, 1), jnp.float32),
             pltpu.VMEM((g * bq, d), jnp.float32),
         ],
         interpret=interpret,
-    )(qg, k, v)
+    )(qg, kh, vh)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, sq, h, d)

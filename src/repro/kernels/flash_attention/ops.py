@@ -10,11 +10,8 @@ from functools import partial
 
 import jax
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "bq", "bk"))
@@ -30,4 +27,4 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         bk //= 2
     return flash_attention_pallas(q, k, v, causal=causal, window=window,
                                   bq=max(bq, 1), bk=max(bk, 1),
-                                  interpret=_on_cpu())
+                                  interpret=interpret_mode())

@@ -5,13 +5,11 @@ from functools import partial
 
 import jax
 
+from repro.kernels import interpret_mode
 from repro.kernels.rglru_scan.kernel import rglru_scan_pallas
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 @partial(jax.jit, static_argnames=("bs", "bd"))
 def rglru_scan(a, b, h0, *, bs: int = 256, bd: int = 512):
-    return rglru_scan_pallas(a, b, h0, bs=bs, bd=bd, interpret=_on_cpu())
+    return rglru_scan_pallas(a, b, h0, bs=bs, bd=bd,
+                             interpret=interpret_mode())

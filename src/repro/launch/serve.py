@@ -1,10 +1,11 @@
 """Serving driver: ``python -m repro.launch.serve --arch <id> [...]``.
 
-Spins up the policy-based ``Engine`` over a (smoke-sized on CPU) model
-and runs a synthetic request workload; ``--partition pp`` additionally
-serves through the Edge-PRUNE partitioned actor graph at the given
-partition point, reporting the boundary traffic — the paper's
-collaborative-inference scenario with an LLM as the workload.
+Spins up the policy-based ``Engine`` over a model — the arch's
+smoke-sized variant by default, its full published config with
+``--no-smoke`` — and runs a synthetic request workload; ``--partition
+pp`` additionally serves through the Edge-PRUNE partitioned actor graph
+at the given partition point, reporting the boundary traffic — the
+paper's collaborative-inference scenario with an LLM as the workload.
 
 ``--policy`` picks the admission policy: ``batch`` is the seed
 static-bucket executor (closed batches, no arrivals); ``fifo`` /
@@ -37,6 +38,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core import Mapping
+from repro.launch.compile_cache import place_compile_cache
 from repro.models import transformer as T
 from repro.runtime.engine import Engine, EngineConfig
 from repro.runtime.serving import PartitionedServeEngine, Request
@@ -81,9 +83,14 @@ def load_trace(path: str, cfg,
 
 
 def main() -> None:
+    place_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the arch's reduced same-family variant; "
+                         "--no-smoke serves its full published width and "
+                         "depth (random weights from PRNGKey(0))")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--max-new", type=int, default=16)

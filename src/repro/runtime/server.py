@@ -56,6 +56,11 @@ no matter how many clients connect.
 Run it::
 
     PYTHONPATH=src python -m repro.runtime.server --tiny --port 8800
+    PYTHONPATH=src python -m repro.runtime.server --arch qwen2_1_5b \
+        --paged --prefix-cache --prefill-chunk 256 --max-len 2048
+
+``--arch`` serves a registry config at full width with random weights
+from ``PRNGKey(0)`` (no checkpoint loading).
 
 ``--smoke`` starts the server, streams one request through the HTTP
 surface, checks ``/health/ready`` and ``/status``, and exits — the CI
@@ -375,22 +380,29 @@ def _completion_json(c) -> Dict[str, Any]:
 
 
 # ---------------------------------------------------------------------------
-# CLI: PYTHONPATH=src python -m repro.runtime.server --tiny [--smoke]
+# CLI: PYTHONPATH=src python -m repro.runtime.server --tiny|--arch ID [--smoke]
 # ---------------------------------------------------------------------------
 
 
-def _build_tiny_engine(args):
-    """A ~1M-param demo model so the server runs anywhere (CI included)."""
+def _build_engine(args):
+    """``--tiny``: a ~1M-param demo model so the server runs anywhere (CI
+    included). ``--arch``: the registry config at full published width
+    and depth. Both are randomly initialized from ``PRNGKey(0)``."""
     import jax
 
+    from repro.configs import get_config
     from repro.models import transformer as T
     from repro.models.config import ModelConfig
     from repro.runtime.engine import EngineConfig
 
-    cfg = ModelConfig(
-        name="server-tiny", arch_type="dense", n_layers=2, d_model=64,
-        n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=256, dtype="float32",
-        param_dtype="float32", attn_chunk=16, remat=False)
+    if args.tiny:
+        cfg = ModelConfig(
+            name="server-tiny", arch_type="dense", n_layers=2, d_model=64,
+            n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=256,
+            dtype="float32", param_dtype="float32", attn_chunk=16,
+            remat=False)
+    else:
+        cfg = get_config(args.arch)
     params = T.init_params(cfg, jax.random.PRNGKey(0))
     ec = EngineConfig.from_args(args, max_len=args.max_len,
                                 admission=args.policy or "fifo")
@@ -460,6 +472,8 @@ def _smoke(url: str, trace_out: Optional[str] = None) -> None:
 def main(argv=None) -> None:
     import argparse
 
+    from repro.configs import ARCH_IDS, canonical, get_config
+    from repro.launch.compile_cache import place_compile_cache
     from repro.runtime.engine import EngineConfig
 
     ap = argparse.ArgumentParser(
@@ -474,6 +488,10 @@ def main(argv=None) -> None:
                     help="open-request bound before /generate returns 429")
     ap.add_argument("--tiny", action="store_true",
                     help="serve a tiny randomly-initialized demo model")
+    ap.add_argument("--arch", default=None,
+                    help="serve this registry config (e.g. qwen2_1_5b) at "
+                         "full width, randomly initialized from "
+                         "PRNGKey(0)")
     ap.add_argument("--smoke", action="store_true",
                     help="start, stream one request, probe health/status/"
                          "metrics/trace, exit (CI liveness gate; implies "
@@ -484,13 +502,20 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     if args.smoke:
         args.observability = True
-    if not args.tiny:
-        ap.error("only --tiny is wired up in this repro (checkpoint "
-                 "loading for the real configs is a later PR)")
+    if args.tiny == (args.arch is not None):
+        ap.error("give exactly one of --tiny or --arch <id>; both serve "
+                 "random weights (checkpoint loading is not supported)")
+    if args.arch is not None and canonical(args.arch) not in ARCH_IDS:
+        ap.error(f"unknown --arch {args.arch!r}; one of {ARCH_IDS}")
+    if args.arch is not None and get_config(args.arch).frontend:
+        ap.error(f"--arch {args.arch}: /generate carries token ids only, "
+                 "so frontend (vision/audio embedding) configs cannot be "
+                 "served over HTTP")
     if (args.policy or "fifo") == "batch":
         ap.error("--policy batch is the closed-batch executor; the server "
                  "needs a continuous policy (fifo | priority | edf)")
-    engine = _build_tiny_engine(args)
+    place_compile_cache()
+    engine = _build_engine(args)
     sc = ServerConfig(host=args.host,
                       port=0 if args.smoke else args.port,
                       max_inflight=args.max_inflight)

@@ -349,3 +349,18 @@ def test_multi_tenant_victim_cache_over_http(setup):
         assert pc["victim_hits"] > before, \
             "second round never hit the parked chains"
         assert pc["prefill_tokens_saved"] > 0 and pc["bytes_saved"] > 0
+
+
+@pytest.mark.parametrize("argv", [
+    [],                                         # neither --tiny nor --arch
+    ["--tiny", "--arch", "qwen2_1_5b"],         # both
+    ["--arch", "llava_next_mistral_7b"],        # needs frontend embeds
+    ["--arch", "no_such_model"],
+    ["--arch", "qwen2_1_5b", "--policy", "batch"],
+])
+def test_cli_rejects_unservable_arguments(argv):
+    """The CLI refuses what it cannot serve before building a model."""
+    from repro.runtime.server import main
+    with pytest.raises(SystemExit) as exc:
+        main(argv)
+    assert exc.value.code == 2
