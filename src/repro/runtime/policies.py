@@ -141,24 +141,32 @@ class BatchAdmission:
 # preemption policies
 # ---------------------------------------------------------------------------
 # Candidates are the tickets currently holding KV blocks (active slots
-# plus an in-flight chunked prefill), minus the slot whose growth needs
-# the blocks. .pick returns the victim ticket.
+# plus an in-flight chunked prefill), the slot whose growth needs the
+# blocks included. .pick returns the victim ticket: the holder the
+# policy ranks lowest. Progress rule: a grower may only evict a holder
+# ranked below itself; when the pick is the grower, it yields — it is
+# requeued, and the holders above it keep their blocks. Growth thus
+# never preempts the top-ranked holder, whose worst case is validated to
+# fit the whole pool at submit time, so it finishes, and by induction
+# every drain ends. (Leaving the grower out of the candidates breaks
+# this: a newer grower evicts the older holder, which is re-admitted as
+# the newer one and evicts it back, for ever.)
 #
 # Under prefix sharing (``prefix_cache``), evicting a victim *releases
 # its references* rather than freeing blocks outright: a block the
 # victim shares with another live request stays resident (refcount > 0)
 # and only the victim's private blocks return to the pool. A preemption
 # may therefore reclaim fewer blocks than the victim's context length
-# suggests; the scheduler keeps preempting until growth succeeds, which
-# terminates because the last survivor's worst case is validated to fit
-# the whole pool at submit time.
+# suggests; the scheduler keeps preempting until growth succeeds or the
+# grower yields.
 
 
 class EvictLatest:
-    """Admission order wins: preempt the latest-admitted request, so the
-    oldest work always makes progress (no livelock — the survivor set
-    shrinks toward the single oldest request, whose worst case is
-    validated to fit the pool at submit time)."""
+    """Admission order wins: preempt the latest-admitted holder. A
+    grower only ever evicts requests admitted after it, and yields when
+    it is itself the latest, so the oldest admission always makes
+    progress and two requests can never take turns restarting each
+    other."""
 
     name = "evict-latest"
 
@@ -169,7 +177,10 @@ class EvictLatest:
 class LowestPriority:
     """Preempt the lowest-priority holder; among equals, the latest
     admitted. High-priority work keeps its KV blocks under pool pressure
-    at the cost of restarting background requests."""
+    at the cost of restarting background requests: a grower only evicts
+    a holder of lower priority, or of equal priority admitted later, and
+    yields otherwise, so the oldest admission of the highest priority
+    always makes progress."""
 
     name = "lowest-priority"
 

@@ -764,23 +764,21 @@ class ContinuousScheduler:
     def _finished(self, ticket: _Ticket) -> bool:
         return len(ticket.emitted) >= ticket.req.max_new_tokens
 
-    def _pick_preempt_victim(self, exclude: int) -> Optional[int]:
-        """Ask the preemption policy for a victim among current block
-        holders other than ``exclude`` — an in-flight chunked prefill
-        counts (it holds its prompt blocks), so a pool dried out by a
-        half-prefilled prompt can still be reclaimed."""
-        cands = [tk for s, tk in self.active.items() if s != exclude]
-        if self._chunking is not None and self._chunking.slot != exclude:
+    def _pick_preempt_victim(self) -> int:
+        """Ask the preemption policy for a victim among the current block
+        holders: every active slot, the growing one included, and an
+        in-flight chunked prefill (it holds its prompt blocks, so a pool
+        dried out by a half-prefilled prompt can still be reclaimed)."""
+        cands = list(self.active.values())
+        if self._chunking is not None:
             cands.append(self._chunking.ticket)
-        if not cands:
-            return None
         return self.preemption.pick(cands).slot
 
     def _preempt(self, slot: int, t0: float) -> Optional[Completion]:
-        """Evict-and-requeue to reclaim blocks for another request's
-        decode growth: the victim restarts from its prompt (greedy decode
-        makes the re-run bit-identical) — or completes as "failed" if its
-        restart budget is spent (the returned Completion)."""
+        """Evict-and-requeue to reclaim blocks for decode growth (another
+        request's, or its own when the grower yields): the victim restarts
+        from its prompt (greedy decode makes the re-run bit-identical) —
+        or completes as "failed" once its restart budget is spent."""
         if self._chunking is not None and self._chunking.slot == slot:
             ticket = self._chunking.ticket
             self._chunking = None
@@ -795,11 +793,15 @@ class ContinuousScheduler:
     def _grow_blocks(self, t0: float) -> List[Completion]:
         """Paged decode growth: before a decode step, every active slot
         whose next KV write position falls in an unallocated page gets one
-        fresh block; when the pool runs dry the preemption policy picks a
-        victim to evict-and-requeue. Guaranteed to terminate because
+        fresh block. On a dry pool the preemption policy ranks every
+        block holder, the grower included, and its pick is requeued: a
+        holder ranked below the grower, or else the grower itself, which
+        yields. Growth thus never preempts the top-ranked holder, and as
         submit() validates that any single request's worst case fits the
-        pool. Returns completions of victims that ran out of restart
-        budget."""
+        pool, a lower-ranked holder exists whenever its growth finds the
+        pool dry: it finishes, and by induction every drain ends (only a
+        later, higher-priority admission outranks it, finitely often).
+        Returns completions of victims that ran out of restart budget."""
         out: List[Completion] = []
         if not self.layout.paged:
             return out
@@ -811,14 +813,12 @@ class ContinuousScheduler:
             if not self.layout.needs_block(slot, pos):
                 continue
             while not self.layout.grow_one(slot, pos):
-                victim = self._pick_preempt_victim(exclude=slot)
-                if victim is None:
-                    raise RuntimeError(
-                        f"paged KV pool exhausted growing slot {slot} with "
-                        f"no other active request to preempt")
+                victim = self._pick_preempt_victim()
                 c = self._preempt(victim, t0)
                 if c is not None:
                     out.append(c)
+                if victim == slot:          # the grower yielded
+                    break
         return out
 
     def _decode_step(self, t0: float) -> List[Completion]:

@@ -118,6 +118,54 @@ def test_growth_can_preempt_inflight_chunked_prefill(setup):
     assert sched.alloc.in_use == 0
 
 
+@pytest.mark.parametrize("preemption,priorities,units", [
+    ("evict-latest", (0, 0), {}),
+    ("evict-latest", (0, 0), dict(units=3, prefill_units=1,
+                                  decode_stages=2)),
+    ("lowest-priority", (0, 1), {}),
+], ids=["single-unit", "disaggregated", "lowest-priority"])
+def test_growth_preemption_drain_ends(setup, preemption, priorities, units):
+    """The smallest drain in which growth preemption can ping-pong: two
+    4-row prompts fill one page each of a 3-block pool, and both need a
+    second page on the same step. A grower may only evict a holder its
+    policy ranks below it, and otherwise yields, so the top-ranked
+    request finishes and the drain ends — rather than the two evicting
+    each other in turn for ever. Driven by step_once() under a step cap,
+    so a regression fails in seconds instead of hanging."""
+    cfg, params = setup
+    rng = np.random.RandomState(0)
+    reqs = [Request(i, rng.randint(0, cfg.vocab_size, 4).astype(np.int32),
+                    max_new_tokens=3, priority=p)
+            for i, p in enumerate(priorities)]
+    sched = ContinuousScheduler(
+        cfg, params, SchedulerConfig(max_slots=2, max_len=8, paged=True,
+                                     block_size=4, num_blocks=4, debug=True,
+                                     **units),
+        preemption=preemption)
+    for r in reqs:
+        sched.submit(r)
+    outs = []
+    cap = (len(reqs) + 1) * sum(r.max_new_tokens for r in reqs)
+    for _ in range(cap):
+        if sched.done:
+            break
+        outs.extend(sched.step_once())
+    assert sched.done, f"drain still running after {cap} steps: " \
+        f"{sched.stats()}"
+    assert sched.stats()["preemptions"] > 0, "growth never ran dry"
+    sched.alloc.check()
+    assert sched.alloc.in_use == 0
+    assert {o.id: len(o.tokens) for o in outs} == {0: 3, 1: 3}
+    # the grower's yield moves time only: tokens match a roomy pool
+    ref = ContinuousScheduler(
+        cfg, params, SchedulerConfig(max_slots=2, max_len=8, paged=True,
+                                     block_size=4, num_blocks=8))
+    for r in reqs:
+        ref.submit(r)
+    assert {o.id: o.tokens for o in outs} == \
+        {o.id: o.tokens for o in ref.run()}
+
+
 def test_paged_rejects_configs_with_no_global_attention(setup):
     """Subquadratic configs are exempt from the max_len rows bound, so
     paged growth could index past the block table; they also have no
