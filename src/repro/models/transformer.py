@@ -40,9 +40,13 @@ _KEEP_F32 = ("lam", "router", "b_if", "w_if", "b")
 
 
 def cast_params_for_compute(params, cfg: ModelConfig):
-    """Master params (fp32) -> compute dtype (bf16) at step entry, the
-    standard mixed-precision scheme: optimizer state and updates stay
-    fp32; matmuls run on the MXU in bf16."""
+    """Master params (fp32) -> compute dtype (bf16), the standard
+    mixed-precision scheme: matmuls run on the MXU in bf16. Training
+    casts at every step entry, since optimizer state and updates stay
+    fp32. Serving never updates the weights: ``Engine`` casts once, at
+    construction, and the call at the top of each serving program then
+    meets leaves already in the compute dtype (``astype`` to the same
+    dtype is the identity, so no convert runs)."""
     ct = _dtype(cfg.dtype)
 
     def one(path, leaf):

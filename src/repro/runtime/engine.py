@@ -77,6 +77,8 @@ __all__ = ["Engine", "EngineConfig", "RequestHandle"]
 
 KV_LAYOUTS = ("slotted", "paged")
 
+_cast_for_compute = jax.jit(T.cast_params_for_compute, static_argnums=1)
+
 
 @dataclass
 class EngineConfig:
@@ -414,13 +416,19 @@ class Engine:
     stamp wall-clock arrival instants, and handles wait on condition
     variables instead of stepping. ``asubmit()``/``astream()`` wrap the
     same surface for asyncio callers. ``shutdown()`` (or exiting the
-    engine's ``with`` block) stops the thread."""
+    engine's ``with`` block) stops the thread.
+
+    ``params`` are the caller's master weights (fp32). The engine holds
+    them in the compute dtype (``T.cast_params_for_compute``, cast once
+    here rather than inside every prefill and decode program) and leaves
+    the caller's tree as it was; the ``repro_weight_bytes`` gauge reads
+    the held tree's device bytes."""
 
     def __init__(self, cfg: ModelConfig, params: Any,
                  config: Optional[EngineConfig] = None, *,
                  failures: Optional[List[SlotFailure]] = None):
         self.cfg = cfg
-        self.params = params
+        self.params = params = _cast_for_compute(params, cfg)
         self.config = c = config or EngineConfig()
         if c.kv_layout not in KV_LAYOUTS:
             raise ValueError(f"kv_layout {c.kv_layout!r} not in {KV_LAYOUTS}")
@@ -464,6 +472,10 @@ class Engine:
         # knob is on — counters are mirrored from stats() at snapshot
         # time either way, histograms/spans need enabled=True
         self.obs = Observability(enabled=c.observability)
+        self.obs.registry.gauge(
+            "repro_weight_bytes",
+            help="device bytes of the served weight tree").set(
+                sum(x.nbytes for x in jax.tree_util.tree_leaves(params)))
         if self.batch_mode:
             if c.kv_layout != "slotted" or c.prefill_chunk:
                 raise ValueError(
