@@ -210,6 +210,7 @@ def main() -> int:
     mix, load = cfg["mix"], cfg["load"]
     reqs = traffic.build(mix, load, cfg["seed"], cfg["seconds"], cfg["vocab"])
     sender = Sender(cfg.get("host", "127.0.0.1"), cfg["port"])
+    ready_at = time.monotonic()
     if mix["loop"] == "open":
         out = open_loop(cfg, reqs, sender)
     else:
@@ -217,6 +218,9 @@ def main() -> int:
     lags = sorted(r["sent"] - r["due"] for r in out["records"]
                   if r["sent"] is not None)
     out["lag_p99_s"] = lags[int(0.99 * (len(lags) - 1))] if lags else None
+    # started and ready to send; after ``start_at``, start-up made the
+    # first requests late
+    out["ready_at"] = ready_at
     sys.stdout.write(json.dumps(out) + "\n")
     sys.stdout.flush()
     return 0

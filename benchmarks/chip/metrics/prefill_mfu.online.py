@@ -1,13 +1,13 @@
 """Prefill's share of the chip's bf16 peak: the prompt tokens computed
 in the window (admitted minus served from the prefix cache) times the
-operations of one prompt token, over the time the scheduler spent in
-prefill (``repro_step_prefill_seconds``). A prompt token's attention
-context is taken as the middle of the uncached part of each prompt due
-in the window, with the cached part leading."""
+operations of one prompt token (the model family's count), over the
+time the scheduler spent in prefill (``repro_step_prefill_seconds``).
+A prompt token's attention context is taken as the middle of the
+uncached part of each prompt due in the window, with the cached part
+leading."""
 import numpy as np
 
 from stats import counter_delta, hist_delta, window_requests
-from workcount import prompt_flops_per_token
 
 
 def read(run):
@@ -21,5 +21,6 @@ def read(run):
     f = saved / total
     n = np.array([r["prompt_len"] for r in reqs], float)
     ctx = float(np.mean(n * (1.0 + f) / 2.0))
-    flops = (total - saved) * prompt_flops_per_token(run["spec"], ctx)
+    flops = (total - saved) * run["family"].prompt_flops_per_token(
+        run["spec"], ctx)
     return 100.0 * flops / busy[1] / run["peak"]["bf16_flops_per_s"]

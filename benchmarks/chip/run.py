@@ -6,9 +6,11 @@
 
 The cell is an entry of ``workloads`` in ``BENCHMARK.json``. Everything
 else is found by name: the configuration in ``configs/<config>.json``,
-the traffic mix in ``traffic/<traffic>.json``, the cell's offered load
-and its correctness limits in ``cells/<cell>.json``, and one reader per
-metric in ``metrics/<metric>.py``.
+its model family in ``families/<family>.py`` (the configuration's
+``family``, ``dense_gqa`` where it names none), the traffic mix in
+``traffic/<traffic>.json``, the cell's offered load and its correctness
+limits in ``cells/<cell>.json``, and one reader per metric in
+``metrics/<metric>.py``.
 
 This process holds the chip. It draws the weights from ``--seed`` on
 the device, builds the served model (``Engine`` behind ``EngineServer``,
@@ -20,7 +22,7 @@ window, and reports the per-layer metrics instead of the end-to-end
 ones.
 
 After the window the served model is freed and a sample of the finished
-requests, the longest among them, is compared with the float32
+requests, the longest among them, is compared with the family's float32
 reference (``reference.py``), which draws its own copy of the weights
 from the seed. The last line of standard output is one JSON object:
 ``correct``, ``attempted``, ``failed``, ``metrics``, ``device``, with
@@ -36,6 +38,7 @@ import time
 T_START = time.monotonic()
 
 import argparse  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -62,10 +65,20 @@ GRACE_S = 60.0
 FILL_TIMEOUT_S = 180.0
 # after the window: the longest wait for cut requests to leave the server
 DRAIN_TIMEOUT_S = 240.0
+# from the load generator's spawn to its first due request: its start-up
+# (interpreter, imports, building the requests) has to fit in it
+LOADGEN_LEAD_S = 1.0
 # --trace 1: seconds of the window the profiler records
 TRACE_S = 5.0
 COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
                   "/jax/core/compile/jaxpr_trace_duration")
+# the model families, one module each, and the names every one exposes
+FAMILIES = HERE / "families"
+DEFAULT_FAMILY = "dense_gqa"
+FAMILY_NAMES = ("Spec", "spec", "model_config", "make_params", "hidden",
+                "logits_at", "quantize", "param_count",
+                "decode_flops_per_token", "prompt_flops_per_token",
+                "kv_row_bytes", "weight_bytes")
 
 
 def log(msg: str) -> None:
@@ -107,12 +120,40 @@ class CompileLog:
         return out
 
 
-def model_config(name: str, cfg: dict):
-    from repro.models.config import ModelConfig
-    keys = ModelConfig.__dataclass_fields__
-    kw = {k: v for k, v in cfg.items() if k in keys and k != "name"}
-    return ModelConfig(name=name, arch_type="dense", layer_pattern=("attn",),
-                       **kw)
+def family(cfg: dict):
+    """The module of the configuration's model family,
+    ``families/<family>.py``; ``dense_gqa`` where the configuration
+    names none. It holds everything that knows the model's shape: its
+    sizes (``spec``), the served config, the seeded weights, the
+    reference's layers and the work counts (``FAMILY_NAMES``)."""
+    name = cfg.get("family", DEFAULT_FAMILY)
+    path = FAMILIES / f"{name}.py"
+    if not path.is_file():
+        known = sorted(p.stem for p in FAMILIES.glob("*.py"))
+        raise ValueError(f"no model family {name!r} in {FAMILIES}; "
+                         f"known: {known}")
+    return _load_family(path)
+
+
+def _load(path: Path, name: str):
+    """The module in the file ``path``, loaded under ``name``."""
+    mod_spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    # a dataclass looks its module up while the module runs
+    sys.modules[name] = mod
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
+@functools.cache
+def _load_family(path: Path):
+    """One module per file, so that the jitted functions that take it
+    as a static argument compile once per process."""
+    mod = _load(path, "family_" + path.stem)
+    missing = [n for n in FAMILY_NAMES if not hasattr(mod, n)]
+    if missing:
+        raise ValueError(f"model family {path} lacks {missing}")
+    return mod
 
 
 def warm(engine, mix: dict, vocab: int) -> None:
@@ -177,7 +218,7 @@ def measure(srv, cfg: dict, mix: dict, load: dict, seed: int,
     """Drive the live server with the load generator for one window."""
     import jax
     engine = srv.engine
-    start_at = time.monotonic() + 1.0
+    start_at = time.monotonic() + LOADGEN_LEAD_S
     child = subprocess.Popen(
         [sys.executable, str(HERE / "loadgen.py")], stdin=subprocess.PIPE,
         stdout=subprocess.PIPE, text=True)
@@ -225,6 +266,7 @@ def measure(srv, cfg: dict, mix: dict, load: dict, seed: int,
         time.sleep(0.2)
     gen = json.loads(out.strip().splitlines()[-1])
     gen["window"] = [w0, w1]
+    gen["ready_margin_s"] = start_at - gen["ready_at"]
     devs = jax.devices()
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                for d in devs)
@@ -259,7 +301,7 @@ def traced_part(w0: float, seconds: float):
 # -- correctness -----------------------------------------------------------
 
 
-def compare(spec, cfg: dict, mix: dict, load: dict, seed: int,
+def compare(fam, spec, cfg: dict, mix: dict, load: dict, seed: int,
             seconds: float, records: list, limits: dict,
             control: str = "") -> dict:
     """Compare a sample of the finished requests with the reference.
@@ -269,7 +311,6 @@ def compare(spec, cfg: dict, mix: dict, load: dict, seed: int,
     reads several)."""
     import numpy as np
     import reference
-    import weights
     reqs = {r.id: r for r in traffic.build(mix, load, seed, seconds,
                                            cfg["vocab_size"])}
     done = {}
@@ -295,14 +336,15 @@ def compare(spec, cfg: dict, mix: dict, load: dict, seed: int,
     kinds = [k for k in control.split(",") if k]
     alt = {}
     for kind in kinds:
-        q = reference.quantize(weights.make_params(spec, seed), kind)
-        alt[kind] = [reference.picks(spec, q, toks)[where]
+        q = fam.quantize(fam.make_params(spec, seed), kind)
+        alt[kind] = [reference.picks(fam, spec, q, toks)[where]
                      for toks, where in seqs]
         del q
-    params = weights.make_params(spec, seed)
+    params = fam.make_params(spec, seed)
     served, margins, ctrl = [], [], {k: [] for k in kinds}
     for k, (i, (toks, where)) in enumerate(zip(pick, seqs)):
-        got = reference.gaps(spec, params, toks, where, done[i]["tokens"],
+        got = reference.gaps(fam, spec, params, toks, where,
+                             done[i]["tokens"],
                              *[alt[kind][k] for kind in kinds])
         served.append(got[0])
         margins.append(got[-1])
@@ -370,12 +412,8 @@ def cell_metrics(manifest: dict, cell: str, trace: bool) -> list:
 
 
 def read_metric(name: str, run: dict):
-    path = HERE / "metrics" / f"{name}.py"
-    mod_spec = importlib.util.spec_from_file_location(
-        "metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read(run)
+    return _load(HERE / "metrics" / f"{name}.py",
+                 "metric_" + name.replace(".", "_")).read(run)
 
 
 # -- one run ---------------------------------------------------------------
@@ -388,20 +426,20 @@ def run_cell(cell: dict, cfg: dict, mix: dict, load: dict, manifest: dict,
     object (without ``device``'s platform fields)."""
     import jax
 
-    import weights
     import workcount
     from repro.runtime.engine import Engine, EngineConfig
     from repro.runtime.server import EngineServer, ServerConfig
 
     compiles = CompileLog()
-    spec = weights.Spec.from_config(cfg)
+    fam = family(cfg)
+    spec = fam.spec(cfg)
     dev = jax.devices()[0]
     # no device numbers off the chip: the readers that need a peak read
     # nothing on another platform
     peak = workcount.peaks(dev.device_kind) if dev.platform == "tpu" \
         else None
-    params = jax.block_until_ready(weights.make_params(spec, seed))
-    engine = Engine(model_config(cell["config"], cfg), params,
+    params = jax.block_until_ready(fam.make_params(spec, seed))
+    engine = Engine(fam.model_config(cell["config"], cfg), params,
                     EngineConfig(**cfg["engine"], observability=trace))
     del params
     srv = EngineServer(engine, ServerConfig(port=0, **cfg["server"]))
@@ -414,12 +452,13 @@ def run_cell(cell: dict, cfg: dict, mix: dict, load: dict, manifest: dict,
     del srv, engine
     gc.collect()
     log(f"window: lag_p99_s={m['lag_p99_s']} "
+        f"loadgen_ready_margin_s={m['ready_margin_s']:.3f} "
         f"compiles_in_window={m['compiles']} "
         f"memory_peak_bytes={m['memory_peak_bytes']} "
         f"setup_s={m['setup_s']:.3f}")
     if m["trace"] is not None:
         log(f"trace: modules={json.dumps(m['trace'].get('modules'))}")
-    run = dict(m, spec=spec, engine=cfg["engine"], peak=peak)
+    run = dict(m, family=fam, spec=spec, engine=cfg["engine"], peak=peak)
     names = cell_metrics(manifest, cell["name"], trace)
     metrics = {}
     for mm in names:
@@ -442,7 +481,7 @@ def run_cell(cell: dict, cfg: dict, mix: dict, load: dict, manifest: dict,
                      or r["finish"] is None)
     else:
         failed = sum(1 for r in recs if r["status"] not in (0, 200))
-    chk = compare(spec, cfg, mix, load, seed, seconds, m["records"],
+    chk = compare(fam, spec, cfg, mix, load, seed, seconds, m["records"],
                   load.get("limits", {}), control)
     log(f"compared: requests={chk['compared']['compared_requests']['value']}"
         f" served={json.dumps(chk['served'])}")

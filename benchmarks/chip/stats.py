@@ -11,8 +11,10 @@ A run record (``run`` below) is the dict the harness hands every reader:
   open and close (lifecycle counters, and with ``--trace 1`` the
   scheduler's histograms);
 * ``trace``: the profiler trace's reduction (``--trace 1``), or None;
-* ``spec``: the model's sizes (``weights.Spec``); ``engine``: its engine
-  settings; ``peak``: the chip's peaks; ``setup_s``.
+* ``family``: the model family's module (``families/<family>.py``, with
+  its work counts); ``spec``: the model's sizes (the family's ``Spec``);
+  ``engine``: its engine settings; ``peak``: the chip's peaks;
+  ``setup_s``.
 
 A reader returns None when the run holds nothing for it to read.
 """

@@ -41,16 +41,16 @@ def main(argv=None) -> int:
     import numpy as np
 
     import stats
-    import weights
     from repro.runtime.engine import Engine, EngineConfig
     from repro.runtime.server import EngineServer, ServerConfig
     if jax.devices()[0].platform != "tpu":
         run.log("sweep.py: needs a TPU")
         return 3
     compiles = run.CompileLog()
-    spec = weights.Spec.from_config(cfg)
-    engine = Engine(run.model_config(args.config, cfg),
-                    weights.make_params(spec, args.seed),
+    fam = run.family(cfg)
+    spec = fam.spec(cfg)
+    engine = Engine(fam.model_config(args.config, cfg),
+                    fam.make_params(spec, args.seed),
                     EngineConfig(**cfg["engine"]))
     with EngineServer(engine, ServerConfig(port=0, **cfg["server"])) as srv:
         run.warm(engine, mix, cfg["vocab_size"])

@@ -3,7 +3,8 @@ harness's ``correct`` against a broken timed path (CPU, small sizes).
 
 The reference is compared on logits with the model's chunked prefill
 (resumed from the prefix cache on the second request) and then its
-paged decode, all through ``Engine``. Both compute in float32 here, so
+paged decode, all through ``Engine``, with the model family found by
+the harness's resolver (``run.family``). Both compute in float32 here, so
 they agree to rounding: the program's blocked attention and chunked
 extend sum in another order than the reference's whole-row softmax,
 which moves a logit by about 1e-6 at these widths. The tolerance, 1e-3,
@@ -24,7 +25,7 @@ CHIP = ROOT / "benchmarks" / "chip"
 sys.path.insert(0, str(CHIP))
 
 import reference  # noqa: E402
-import weights  # noqa: E402
+import run  # noqa: E402
 
 LOGIT_TOL = 1e-3
 
@@ -34,10 +35,9 @@ TINY = {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 2,
         "param_dtype": "float32"}
 
 
-def _engine(cfg, params, chunk=8):
-    import run
+def _engine(fam, cfg, params, chunk=8):
     from repro.runtime.engine import Engine, EngineConfig
-    return Engine(run.model_config("tiny", cfg), params, EngineConfig(
+    return Engine(fam.model_config("tiny", cfg), params, EngineConfig(
         max_slots=2, max_len=96, kv_layout="paged", block_size=4,
         prefill_chunk=chunk, prefix_cache=True, victim_cache=True))
 
@@ -45,14 +45,32 @@ def _engine(cfg, params, chunk=8):
 @pytest.mark.parametrize("tied,fraction", [(True, 1.0), (False, 0.5)])
 def test_reference_matches_chunked_prefill_then_paged_decode(
         monkeypatch, tied, fraction):
+    _chunked_prefill_then_paged_decode(
+        monkeypatch, dict(TINY, tie_embeddings=tied, rope_fraction=fraction))
+
+
+def test_a_family_is_added_by_a_file_alone(monkeypatch, tmp_path):
+    """A model family is one file in the families directory: a module
+    that re-exports dense_gqa under another name is served, drawn and
+    compared through the resolver, with no edit of the harness."""
+    (tmp_path / "dense_alias.py").write_text(
+        "from families.dense_gqa import *  # noqa: F401,F403\n")
+    monkeypatch.setattr(run, "FAMILIES", tmp_path)
+    cfg = dict(TINY, family="dense_alias", tie_embeddings=False,
+               rope_fraction=0.5)
+    assert run.family(cfg).__name__ == "family_dense_alias"
+    _chunked_prefill_then_paged_decode(monkeypatch, cfg)
+
+
+def _chunked_prefill_then_paged_decode(monkeypatch, cfg):
     import jax
     import jax.numpy as jnp
     from repro.runtime import policies
     from repro.runtime.scheduler import Request
 
-    cfg = dict(TINY, tie_embeddings=tied, rope_fraction=fraction)
-    spec = weights.Spec.from_config(cfg)
-    params = weights.make_params(spec, 2 ** 31 + 3)
+    fam = run.family(cfg)
+    spec = fam.spec(cfg)
+    params = fam.make_params(spec, 2 ** 31 + 3)
     seen = []
 
     def sampler(self, logits):
@@ -60,7 +78,7 @@ def test_reference_matches_chunked_prefill_then_paged_decode(
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     monkeypatch.setattr(policies.Sampler, "__call__", sampler)
-    eng = _engine(cfg, params)
+    eng = _engine(fam, cfg, params)
     rng = np.random.RandomState(0)
     shared = rng.randint(0, 250, 20)
     prompts = [np.concatenate([shared, rng.randint(0, 250, 7)]),
@@ -75,25 +93,27 @@ def test_reference_matches_chunked_prefill_then_paged_decode(
         got = np.stack([seen[0][0]] + [s[0] for s in seen[1:]])
         toks, where = reference.padded(list(p), c.tokens, len(p) + n_new)
         with jax.default_matmul_precision("highest"):
-            x = reference.hidden(spec, params, jnp.asarray(toks))
-            want = np.asarray(reference.logits_at(spec, params, x))[where]
+            x = fam.hidden(spec, params, jnp.asarray(toks))
+            want = np.asarray(fam.logits_at(spec, params, x))[where]
         assert got.shape[0] == n_new
         err = np.abs(got[:, :spec.vocab_size] - want).max()
         assert err < LOGIT_TOL, (i, err)
         # the served tokens are the reference's own picks here
-        g = reference.gaps(spec, params, toks, where, c.tokens)[0]
+        g = reference.gaps(fam, spec, params, toks, where, c.tokens)[0]
         assert g.max() < LOGIT_TOL
     assert eng.stats()["prefix_hits"] >= 1
 
 
 def test_a_wrong_token_shows_as_a_gap():
-    spec = weights.Spec.from_config(dict(TINY, tie_embeddings=True))
-    params = weights.make_params(spec, 5)
+    cfg = dict(TINY, tie_embeddings=True)
+    fam = run.family(cfg)
+    spec = fam.spec(cfg)
+    params = fam.make_params(spec, 5)
     prompt = list(range(3, 30))
     toks, where = reference.padded(prompt, [0] * 8, 40)
-    best = reference.picks(spec, params, toks)[where]
-    right, wrong, margin = reference.gaps(spec, params, toks, where, best,
-                                          (best + 1) % spec.vocab_size)
+    best = reference.picks(fam, spec, params, toks)[where]
+    right, wrong, margin = reference.gaps(fam, spec, params, toks, where,
+                                          best, (best + 1) % spec.vocab_size)
     assert right.max() == 0.0
     assert wrong.min() > 0.0
     assert margin.min() >= 0.0 and margin.max() > 0.0
@@ -149,7 +169,6 @@ def test_harness_run_decides_correct(monkeypatch, loop, fault):
     served token altered where it is produced ("token"), or a decode
     step that returns the KV cache it was given unchanged ("state")."""
     import jax.numpy as jnp
-    import run
     from repro.models import transformer
     from repro.runtime import policies
 
