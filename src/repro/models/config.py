@@ -37,8 +37,28 @@ class MoEConfig:
     n_shared_experts: int = 0
     # Token-choice routing with fixed per-token-group capacity
     # (Switch-Transformer style dense dispatch; see models/moe.py).
+    # Training only: the serving programs route dropless.
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # the experts this device holds, [first, first + count): its share
+    # under expert parallelism. The router keeps all n_experts outputs;
+    # the layer adds only the held experts' part. (0, 0) holds them all.
+    held: Tuple[int, int] = (0, 0)
+
+    def __post_init__(self):
+        first, count = self.held
+        if first < 0 or count < 0 or first + count > self.n_experts:
+            raise ValueError(f"held experts {self.held} outside "
+                             f"0..{self.n_experts}")
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        """(first, count) of the held experts, every expert by default."""
+        return self.held if self.held[1] else (0, self.n_experts)
+
+    @property
+    def holds_all(self) -> bool:
+        return self.held_range == (0, self.n_experts)
 
 
 @dataclass(frozen=True)
@@ -61,6 +81,9 @@ class ModelConfig:
     rope_theta: float = 10_000.0
     rope_theta_global: Optional[float] = None   # gemma3: 1e6 on global layers
     rope_fraction: float = 1.0        # chatglm "RoPE 2d": rotary on half dims
+    # qwen3: RMSNorm over each q and k head (learned head_dim scale)
+    # before the rotary embedding
+    qk_norm: bool = False
 
     # encoder-decoder (audio): n_encoder_layers > 0 enables the encoder
     # stack + cross-attention in every decoder layer.
@@ -164,7 +187,8 @@ class ModelConfig:
         if self.frontend:
             n += self.frontend_dim * d + d * d       # 2-layer projector
         per_kind = {}
-        per_kind["attn"] = d * qkv_out + self.n_heads * hd * d + 2 * d
+        per_kind["attn"] = d * qkv_out + self.n_heads * hd * d + 2 * d \
+            + (2 * hd if self.qk_norm else 0)
         per_kind["attn_local"] = per_kind["attn"]
         per_kind["rglru"] = (d * (2 * d) + self.rglru_conv_width * d + 3 * d
                              + d * d + 2 * d)
@@ -177,7 +201,7 @@ class ModelConfig:
         mlp = 3 * d * self.d_ff if self.d_ff else 0
         if self.moe:
             shared = 3 * d * self.moe.d_ff_expert * self.moe.n_shared_experts
-            routed = 3 * d * self.moe.d_ff_expert * self.moe.n_experts
+            routed = 3 * d * self.moe.d_ff_expert * self.moe.held_range[1]
             router = d * self.moe.n_experts
             mlp = shared + routed + router
         for k in self.layer_kinds:
@@ -192,14 +216,16 @@ class ModelConfig:
         return int(n)
 
     def active_param_count(self) -> int:
-        """Active params per token (MoE: only top-k + shared experts)."""
+        """Active params per token (MoE: only top-k + shared experts; of
+        a held share, its expected part of the top-k)."""
         if not self.moe:
             return self.param_count()
         full = self.param_count()
+        held = self.moe.held_range[1]
         routed_all = self.n_layers * 3 * self.d_model * self.moe.d_ff_expert \
-            * self.moe.n_experts
+            * held
         routed_active = self.n_layers * 3 * self.d_model * self.moe.d_ff_expert \
-            * self.moe.top_k
+            * self.moe.top_k * held / self.moe.n_experts
         return int(full - routed_all + routed_active)
 
     def smoke(self) -> "ModelConfig":
@@ -223,7 +249,8 @@ class ModelConfig:
             # the smoke decode-vs-forward consistency check exact.
             moe = replace(self.moe, n_experts=4, top_k=min(self.moe.top_k, 2),
                           d_ff_expert=64, capacity_factor=4.0,
-                          n_shared_experts=min(self.moe.n_shared_experts, 1))
+                          n_shared_experts=min(self.moe.n_shared_experts, 1),
+                          held=(0, 0))
         return replace(
             self, name=self.name + "-smoke", n_layers=2, d_model=d,
             n_heads=heads, n_kv_heads=kv, head_dim=d // heads,

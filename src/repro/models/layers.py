@@ -353,15 +353,23 @@ def attn_init(key, cfg, dtype) -> dict:
         p["bq"] = jnp.zeros((h, hd), dtype)
         p["bk"] = jnp.zeros((hk, hd), dtype)
         p["bv"] = jnp.zeros((hk, hd), dtype)
+    if cfg.qk_norm:
+        p["q_norm"] = jnp.zeros((hd,), dtype)
+        p["k_norm"] = jnp.zeros((hd,), dtype)
     return p
 
 
 def _qkv(p, x, cfg):
+    """q, k, v of the normed input; with ``qk_norm`` q and k are each
+    RMS-normed over every head's dims (before the rotary embedding)."""
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
     if cfg.qkv_bias:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
+    if cfg.qk_norm:
+        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     return q, k, v
 
 

@@ -1,23 +1,45 @@
-"""Mixture-of-Experts MLP: token-choice top-k routing with fixed capacity.
+"""Mixture-of-Experts MLP: token-choice top-k routing.
 
 Covers qwen2-moe (4 shared + 60 routed, top-4) and qwen3-moe (128 routed,
-top-8). The production formulation is Switch-Transformer-style *dense
-dispatch*: a one-hot dispatch tensor (T, E, C) routes each token to its
-top-k experts' capacity slots; expert FFNs run as one batched einsum over
-the expert dimension, which shards cleanly over the mesh "model" axis
-(expert parallelism) — the dispatch/combine einsums lower to all-to-all-
-like collectives under SPMD. Tokens beyond an expert's capacity are
-dropped (their residual passes through), the standard trade-off.
+top-8). Two formulations share the router (softmax over every expert,
+top-k, renormalised over the k chosen):
+
+* training (``forward``, the train step) routes with fixed capacity
+  (``moe_apply``). ``ModelConfig.moe_impl`` picks the dispatch:
+  "einsum" (the default) is Switch-Transformer-style *dense dispatch*, a
+  one-hot (T, E, C) tensor routing each token to its top-k experts'
+  capacity slots, expert FFNs as one batched einsum over the expert
+  dimension, which shards cleanly over the mesh "model" axis (expert
+  parallelism; the dispatch/combine einsums lower to all-to-all-like
+  collectives under SPMD); "gather" is the index-form dispatch. Tokens
+  beyond an expert's capacity are dropped (their residual passes
+  through), the standard trade-off.
+* serving (``prefill``, ``prefill_extend``, ``decode_step``) routes
+  dropless (``moe_serve``): the (token, choice) pairs are sorted by
+  expert, one grouped matmul (``jax.lax.ragged_dot``) runs over the
+  experts, and the weighted rows are scattered back. Each expert's
+  weights are read once per step, its FLOPs follow the pairs routed to
+  it, and no token is dropped, so a request's output does not depend on
+  its batch-mates. Under a mesh (the sharded serving steps of
+  ``runtime/trainstep``) each "model" shard runs the same path over the
+  experts it holds, and the shards' parts are summed.
+
+``MoEConfig.held`` names the experts this device holds (its share under
+expert parallelism). The router keeps every output; the serving layer
+adds only the held experts' part, and no exchange with the other shares
+is modelled. Training holds every expert.
 
 ``load_balance_loss`` is the usual Switch aux loss: E * sum(frac_tokens *
 frac_router_prob); a router z-loss keeps logits bounded.
 """
 from __future__ import annotations
 
+import math
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import act_fn, dense_init, mlp_apply, mlp_init, rms_norm
 
@@ -25,22 +47,31 @@ from repro.models.layers import act_fn, dense_init, mlp_apply, mlp_init, rms_nor
 def moe_init(key, cfg, dtype) -> dict:
     d = cfg.d_model
     m = cfg.moe
+    held = m.held_range[1]
     ks = jax.random.split(key, 5)
     p = {
         "ln": jnp.zeros((d,), dtype),
         "router": dense_init(ks[0], d, (m.n_experts,), jnp.float32),
-        # stacked expert FFNs: (E, d, f) / (E, f, d)
-        "w_gate": dense_init(ks[1], d, (m.n_experts, m.d_ff_expert),
+        # stacked held expert FFNs: (E_held, d, f) / (E_held, f, d)
+        "w_gate": dense_init(ks[1], d, (held, m.d_ff_expert),
                              dtype).transpose(1, 0, 2),
-        "w_up": dense_init(ks[2], d, (m.n_experts, m.d_ff_expert),
+        "w_up": dense_init(ks[2], d, (held, m.d_ff_expert),
                            dtype).transpose(1, 0, 2),
-        "w_down": dense_init(ks[3], m.d_ff_expert, (m.n_experts, d),
+        "w_down": dense_init(ks[3], m.d_ff_expert, (held, d),
                              dtype).transpose(1, 0, 2),
     }
     if m.n_shared_experts:
         p["shared"] = mlp_init(ks[4], d, m.d_ff_expert * m.n_shared_experts,
                                dtype)
     return p
+
+
+def _top_k(probs: jax.Array, m) -> Tuple[jax.Array, jax.Array]:
+    """The k largest router probabilities and their experts, renormalised
+    over the k chosen (qwen-style ``norm_topk_prob``)."""
+    top_p, top_idx = jax.lax.top_k(probs, m.top_k)
+    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    return top_p, top_idx
 
 
 def _capacity(tokens_per_group: int, cfg) -> int:
@@ -71,9 +102,7 @@ def route(router_logits: jax.Array, cfg, capacity: int, *,
     g, t, e = router_logits.shape
     m = cfg.moe
     probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    top_p, top_idx = jax.lax.top_k(probs, m.top_k)           # (G, T, K)
-    # renormalize the selected probabilities (qwen-style norm_topk_prob)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    top_p, top_idx = _top_k(probs, m)                         # (G, T, K)
 
     # expert one-hot per k-slot: (G, T, K, E)
     onehot = jax.nn.one_hot(top_idx, e, dtype=jnp.int32)
@@ -111,8 +140,7 @@ def route_indices(router_logits: jax.Array, cfg, capacity: int):
     g, t, e = router_logits.shape
     m = cfg.moe
     probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    top_p, top_idx = jax.lax.top_k(probs, m.top_k)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    top_p, top_idx = _top_k(probs, m)
     onehot = jax.nn.one_hot(top_idx, e, dtype=jnp.int32)
     pos = jnp.cumsum(onehot.transpose(0, 2, 1, 3).reshape(g, t * m.top_k, e),
                      axis=1) - 1
@@ -182,10 +210,14 @@ def moe_apply_gather(p, x, cfg, ctx=None) -> Tuple[jax.Array, jax.Array]:
 
 
 def moe_apply(p, x, cfg, ctx=None) -> Tuple[jax.Array, jax.Array]:
-    """Dispatch on cfg.moe_impl: "gather" (default, index-form dispatch)
-    or "einsum" (Switch-style dense dispatch, kept as the reference
-    production path and for ablation)."""
-    if getattr(cfg, "moe_impl", "gather") == "gather":
+    """The training path (fixed capacity). Dispatch on cfg.moe_impl:
+    "einsum" (the default, Switch-style dense dispatch) or "gather"
+    (index-form dispatch, kept for ablation)."""
+    if not cfg.moe.holds_all:
+        raise ValueError(
+            f"{cfg.name}: the capacity path trains every expert; a held "
+            "share of the experts is served by moe_serve")
+    if cfg.moe_impl == "gather":
         return moe_apply_gather(p, x, cfg, ctx=ctx)
     return moe_apply_einsum(p, x, cfg, ctx=ctx)
 
@@ -233,19 +265,21 @@ def moe_apply_einsum(p, x, cfg, ctx=None) -> Tuple[jax.Array, jax.Array]:
 
 
 def moe_apply_dense_oracle(p, x, cfg) -> Tuple[jax.Array, jax.Array]:
-    """Reference: compute EVERY expert for every token, weight by the
-    (renormalized) top-k router probs. No capacity, no dropping — the
-    oracle that ``moe_apply`` approaches as capacity_factor -> inf.
-    O(E/k) overcompute; tests only."""
+    """Reference: compute EVERY held expert for every token, weight by
+    the (renormalized) top-k router probs (zero where not chosen). No
+    capacity, no dropping — the oracle that ``moe_apply`` approaches as
+    capacity_factor -> inf, and that ``moe_serve`` equals. O(E/k)
+    overcompute; tests only."""
     m = cfg.moe
+    first, held = m.held_range
     h = rms_norm(x, p["ln"], cfg.norm_eps)
     logits = jnp.einsum("gtd,de->gte", h.astype(jnp.float32), p["router"])
     probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_idx = jax.lax.top_k(probs, m.top_k)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    top_p, top_idx = _top_k(probs, m)
     weights = jnp.zeros_like(probs)
     weights = jax.vmap(jax.vmap(lambda w, i, v: w.at[i].set(v)))(
         weights, top_idx, top_p)                              # (G, T, E)
+    weights = weights[..., first:first + held]                # held share
     act = act_fn(cfg.act)
     hidden = act(jnp.einsum("gtd,edf->gtef", h, p["w_gate"])) \
         * jnp.einsum("gtd,edf->gtef", h, p["w_up"])
@@ -259,3 +293,115 @@ def moe_apply_dense_oracle(p, x, cfg) -> Tuple[jax.Array, jax.Array]:
     frac_tokens = onehot.mean(1)
     aux = m.n_experts * jnp.mean(jnp.sum(frac_tokens * probs.mean(1), -1))
     return x + y.astype(x.dtype), aux
+
+
+def moe_serve(p, x, cfg, ctx=None, live=None
+              ) -> Tuple[jax.Array, jax.Array]:
+    """The serving path: dropless routing of x: (B, S, D) over the held
+    experts. Returns (x + the held experts' part (+ shared experts),
+    held pairs per held expert (E_held,) int32). ``live`` (B, S) bool
+    marks the rows whose pairs are counted (every row by default); the
+    other rows are computed alike, and only left out of the count.
+
+    Under a mesh (``ctx``) the layer keeps expert parallelism
+    (``_sharded_part``)."""
+    m = cfg.moe
+    h = rms_norm(x, p["ln"], cfg.norm_eps)
+    if ctx is None:
+        y, counts = _held_part(p, h, live, cfg, *m.held_range)
+    else:
+        y, counts = _sharded_part(p, h, live, cfg, ctx.mesh)
+    if m.n_shared_experts:
+        with jax.named_scope("moe_combine"):
+            sh = p["shared"]
+            act = act_fn(cfg.act)
+            y = y + (act(h @ sh["w_gate"]) * (h @ sh["w_up"])) \
+                @ sh["w_down"]
+    return x + y.astype(x.dtype), counts
+
+
+def _held_part(p, h, live, cfg, first, held):
+    """The held experts' part of the layer for normed rows h: (B, S, D),
+    float32, and the live pairs per held expert. ``first`` may be traced
+    (an expert rank's offset under ``_sharded_part``).
+
+    Every (token, choice) pair is keyed by its held expert (pairs whose
+    expert another device holds key past the last), and the pairs are
+    sorted by key. A token's k choices are distinct experts, so at most
+    ``min(k, held)`` of them are held: the first ``tokens * min(k,
+    held)`` sorted rows hold every held pair, and that static bound
+    drops nothing. One grouped matmul per projection
+    (``jax.lax.ragged_dot``, groups = the held pairs per expert) reads
+    each held expert's weights once and computes only the rows routed to
+    it; rows past the held pairs are masked out of the combine, which
+    scatter-adds the weighted rows back to their tokens in float32."""
+    b, s, d = h.shape
+    n, k = b * s, cfg.moe.top_k
+    h = h.reshape(n, d)
+    with jax.named_scope("moe_router"):
+        logits = (h @ p["router"].astype(h.dtype)).astype(jnp.float32)
+        top_p, top_idx = _top_k(jax.nn.softmax(logits, axis=-1), cfg.moe)
+        local = top_idx.reshape(-1) - first                   # (n*k,)
+        mine = (local >= 0) & (local < held)
+        key = jnp.where(mine, local, held)
+        order = jnp.argsort(key, stable=True)[:n * min(k, held)]
+        sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+        counts = sizes if live is None else jnp.bincount(
+            jnp.where(jnp.repeat(live.reshape(-1), k), key, held),
+            length=held + 1)[:held].astype(jnp.int32)
+        tok = order // k
+        kept = mine[order]
+        w = jnp.where(kept, top_p.reshape(-1)[order], 0.0)
+    with jax.named_scope("moe_experts"):
+        xs = h[tok]
+        act = act_fn(cfg.act)
+        g = act(jax.lax.ragged_dot(xs, p["w_gate"], sizes)) \
+            * jax.lax.ragged_dot(xs, p["w_up"], sizes)
+        ys = jax.lax.ragged_dot(g, p["w_down"], sizes)
+    with jax.named_scope("moe_combine"):
+        rows = jnp.where(kept[:, None], ys.astype(jnp.float32), 0.0)
+        y = jnp.zeros((n, d), jnp.float32).at[tok].add(rows * w[:, None])
+    return y.reshape(b, s, d), counts
+
+
+def _sharded_part(p, h, live, cfg, mesh):
+    """``_held_part`` under a mesh, for every expert. Each "model" shard
+    holds whole experts when the axis divides their count (expert
+    parallelism: shard i holds experts [i*E/m, (i+1)*E/m)), else a slice
+    of every expert's width (per-expert tensor parallelism), as the
+    parameter rules lay the banks out; it serves its batch shard's
+    tokens against what it holds, and the shards' parts are summed over
+    "model" in float32. Each part is exact, so the sum is the whole
+    layer's (a share test pins this), and no token is dropped. Returns
+    the part and the pairs per expert of the whole batch."""
+    from repro.sharding.rules import batch_axes
+    m = cfg.moe
+    if not m.holds_all:
+        raise ValueError(f"{cfg.name}: a mesh serves every expert; name no "
+                         "held share")
+    ba = batch_axes(mesh)
+    n_model = mesh.shape["model"]
+    n_batch = math.prod(mesh.shape[a] for a in ba)
+    b, s, _ = h.shape
+    rows = P(ba) if b % n_batch == 0 else \
+        P(None, ba) if s % n_batch == 0 else P()
+    by_expert = m.n_experts % n_model == 0
+    banks = (P("model"),) * 3 if by_expert else (
+        P(None, None, "model"), P(None, None, "model"), P(None, "model"))
+    held = m.n_experts // n_model if by_expert else m.n_experts
+
+    def part(q, hh, ll):
+        first = jax.lax.axis_index("model") * held if by_expert else 0
+        y, counts = _held_part(q, hh, ll, cfg, first, held)
+        if rows != P():
+            counts = jax.lax.psum(counts, ba)
+        return jax.lax.psum(y, "model"), counts
+
+    q = {k: p[k] for k in ("router", "w_gate", "w_up", "w_down")}
+    q_specs = dict(zip(("w_gate", "w_up", "w_down"), banks), router=P())
+    if live is None:
+        live = jnp.ones((b, s), jnp.bool_)
+    return jax.shard_map(
+        part, mesh=mesh, in_specs=(q_specs, rows, rows),
+        out_specs=(rows, P("model") if by_expert else P()))(
+            q, h, live)

@@ -57,6 +57,29 @@ def cast_params_for_compute(params, cfg: ModelConfig):
     return jax.tree_util.tree_map_with_path(one, params)
 
 
+@partial(jax.jit, static_argnums=1)
+def _cast_leaves(leaves, dtypes):
+    return [a.astype(dt) for a, dt in zip(leaves, dtypes)]
+
+
+def hold_for_serving(params, cfg: ModelConfig):
+    """The tree a server holds: ``cast_params_for_compute`` of the
+    caller's tree, cast once. A leaf already in its compute dtype (a
+    bf16 checkpoint's, or one of the leaves kept in fp32) is the
+    caller's own device buffer, not a copy, so serving a tree in its
+    compute dtype takes no second copy of it; the other leaves go
+    through one compiled cast."""
+    leaves, tree = jax.tree_util.tree_flatten(params)
+    want = jax.tree_util.tree_leaves(
+        jax.eval_shape(lambda p: cast_params_for_compute(p, cfg), params))
+    cast = [i for i, (a, w) in enumerate(zip(leaves, want))
+            if a.dtype != w.dtype or not isinstance(a, jax.Array)]
+    for i, a in zip(cast, _cast_leaves([leaves[i] for i in cast],
+                                       tuple(want[i].dtype for i in cast))):
+        leaves[i] = a
+    return jax.tree_util.tree_unflatten(tree, leaves)
+
+
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
@@ -440,18 +463,43 @@ def _layer_prefill(p, x, kind, cfg, *, positions, cache_size, enc_out,
         if cfg.qkv_bias:
             ck, cv = ck + cp["bk"], cv + cp["bv"]
         c = {**c, "cross_k": ck.astype(x.dtype), "cross_v": cv.astype(x.dtype)}
+    x, held = _serve_mlp(p, x, cfg, ctx)
+    return x, c, held
+
+
+def _serve_mlp(p, x, cfg, ctx=None, live=None):
+    """The MLP sublayer of a serving program: experts route dropless
+    (``moe_serve``), under a mesh with expert parallelism. Returns (x,
+    the pairs per held expert of the ``live`` rows, or None)."""
     if "moe" in p:
-        x, _ = M.moe_apply(p["moe"], x, cfg, ctx=ctx)
-    elif "mlp" in p:
+        return M.moe_serve(p["moe"], x, cfg, ctx=ctx, live=live)
+    if "mlp" in p:
         x = L.mlp_apply(p["mlp"], x, cfg)
-    return x, c
+    return x, None
+
+
+def _routing(scan_held, rem_held, tokens, cfg: ModelConfig):
+    """What a serving program reports of its expert routing: ``held``,
+    the pairs routed to each held expert, (expert layers, E_held) int32
+    in layer order, and ``routed``, every (token, choice) pair of every
+    expert layer, held here or not. A decode step counts its live rows
+    alone (``_live``); a prefill or chunk counts every row, padding
+    included."""
+    per = [h for h in scan_held if h is not None]
+    parts = [jnp.stack(per, 1).reshape(-1, per[0].shape[-1])] if per else []
+    parts += [h[None] for h in rem_held if h is not None]
+    held = jnp.concatenate(parts)
+    return {"held": held,
+            "routed": jnp.asarray(tokens * cfg.moe.top_k * held.shape[0],
+                                  jnp.int32)}
 
 
 def prefill(params, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
-            max_len: int, ctx=None
-            ) -> Tuple[jax.Array, Dict[str, Any], jax.Array]:
+            max_len: int, ctx=None, moe_counts: bool = False):
     """Run the prompt through the model, materializing the decode cache.
-    Returns (last-position logits (B, V), cache, cache_len (B,))."""
+    Returns (last-position logits (B, V), cache, cache_len (B,)), and
+    with ``moe_counts`` the program's expert routing (``_routing``)
+    last."""
     params = cast_params_for_compute(params, cfg)
     tokens = batch.get("tokens")
     embeds = batch.get("embeds")
@@ -466,29 +514,35 @@ def prefill(params, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
     period = cfg.layer_pattern
     cache: Dict[str, Any] = {"scan": [], "rem": []}
 
+    scan_held, rem_held = [], []
     if cfg.n_periods:
         def body(x, slice_params):
-            caches = []
+            caches, helds = [], []
             for i, kind in enumerate(period):
-                x, c = _layer_prefill(
+                x, c, held = _layer_prefill(
                     slice_params[i], x, kind, cfg, positions=positions,
                     cache_size=_cache_size_for(kind, cfg, max_len),
                     enc_out=enc_out, ctx=ctx)
                 caches.append(c)
+                helds.append(held)
             if ctx is not None:
                 x = ctx.act(x)
-            return x, caches
-        x, caches = jax.lax.scan(body, x, params["scan"])
+            return x, (caches, helds)
+        x, (caches, scan_held) = jax.lax.scan(body, x, params["scan"])
         cache["scan"] = caches
     for i, kind in enumerate(cfg.remainder_kinds):
-        x, c = _layer_prefill(params["rem"][i], x, kind, cfg,
-                              positions=positions,
-                              cache_size=_cache_size_for(kind, cfg, max_len),
-                              enc_out=enc_out, ctx=ctx)
+        x, c, held = _layer_prefill(
+            params["rem"][i], x, kind, cfg, positions=positions,
+            cache_size=_cache_size_for(kind, cfg, max_len),
+            enc_out=enc_out, ctx=ctx)
         cache["rem"].append(c)
+        rem_held.append(held)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _head_logits(x[:, -1], params, cfg)
-    return logits, cache, jnp.full((b,), s, jnp.int32)
+    out = (logits, cache, jnp.full((b,), s, jnp.int32))
+    if moe_counts:
+        out += (_routing(scan_held, rem_held, b * s, cfg),)
+    return out
 
 
 def _layer_decode(p, x, kind, cfg, *, cache, cache_len, enc_out,
@@ -521,51 +575,66 @@ def _layer_decode(p, x, kind, cfg, *, cache, cache_len, enc_out,
             q[:, 0], ck, cv, jnp.full((b,), ck.shape[1], jnp.int32))
         x = x + jnp.einsum("bhk,hkd->bd", o, p["cross"]["wo"])[:, None]
         c = {**c, "cross_k": ck, "cross_v": cv}
-    if "moe" in p:
-        x, _ = M.moe_apply(p["moe"], x, cfg, ctx=ctx)
-    elif "mlp" in p:
-        x = L.mlp_apply(p["mlp"], x, cfg)
-    return x, c
+    x, held = _serve_mlp(p, x, cfg, ctx,
+                         live=_live(cache_len) if "moe" in p else None)
+    return x, c, held
+
+
+def _live(cache_len: jax.Array) -> jax.Array:
+    """(B, 1) rows of a decode step that hold a sequence: a free slot
+    is kept at length 0 by the scheduler, a live one has its prompt."""
+    return (cache_len > 0)[:, None]
 
 
 def decode_step(params, cfg: ModelConfig, token: jax.Array,
                 cache: Dict[str, Any], cache_len: jax.Array, *,
-                block_tables: Optional[jax.Array] = None, ctx=None
-                ) -> Tuple[jax.Array, Dict[str, Any], jax.Array]:
+                block_tables: Optional[jax.Array] = None, ctx=None,
+                moe_counts: bool = False):
     """One serving step: next-token logits for one new token per sequence.
     token: (B,) int32; cache_len: (B,) current context length.
     ``block_tables`` (B, nb) switches global-attention layers to the paged
     cache layout (``init_paged_cache``): they stream only the live blocks
     the tables name, while every slot-indexed leaf (local windows,
-    recurrent state, cross K/V) behaves exactly as on the dense path."""
+    recurrent state, cross K/V) behaves exactly as on the dense path.
+    Returns (logits, cache, cache_len + 1), and with ``moe_counts`` the
+    step's expert routing (``_routing``) last."""
     params = cast_params_for_compute(params, cfg)
     x = params["embed"].astype(_dtype(cfg.dtype))[token][:, None] \
         * math.sqrt(cfg.d_model)
     enc_out = None   # cross K/V live inside each layer's cache
     period = cfg.layer_pattern
     new_cache: Dict[str, Any] = {"scan": [], "rem": []}
+    scan_held, rem_held = [], []
 
     if cfg.n_periods:
         def body(x, scanned):
             slice_params, slice_cache = scanned
-            new_cs = []
+            new_cs, helds = [], []
             for i, kind in enumerate(period):
-                x, c = _layer_decode(slice_params[i], x, kind, cfg,
-                                     cache=slice_cache[i],
-                                     cache_len=cache_len, enc_out=enc_out,
-                                     tables=block_tables, ctx=ctx)
+                x, c, held = _layer_decode(
+                    slice_params[i], x, kind, cfg, cache=slice_cache[i],
+                    cache_len=cache_len, enc_out=enc_out,
+                    tables=block_tables, ctx=ctx)
                 new_cs.append(c)
-            return x, new_cs
-        x, ncs = jax.lax.scan(body, x, (params["scan"], cache["scan"]))
+                helds.append(held)
+            return x, (new_cs, helds)
+        x, (ncs, scan_held) = jax.lax.scan(body, x, (params["scan"],
+                                                     cache["scan"]))
         new_cache["scan"] = ncs
     for i, kind in enumerate(cfg.remainder_kinds):
-        x, c = _layer_decode(params["rem"][i], x, kind, cfg,
-                             cache=cache["rem"][i], cache_len=cache_len,
-                             enc_out=enc_out, tables=block_tables, ctx=ctx)
+        x, c, held = _layer_decode(params["rem"][i], x, kind, cfg,
+                                   cache=cache["rem"][i], cache_len=cache_len,
+                                   enc_out=enc_out, tables=block_tables,
+                                   ctx=ctx)
         new_cache["rem"].append(c)
+        rem_held.append(held)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _head_logits(x[:, 0], params, cfg)
-    return logits, new_cache, cache_len + 1
+    out = (logits, new_cache, cache_len + 1)
+    if moe_counts:
+        out += (_routing(scan_held, rem_held,
+                                _live(cache_len).sum(dtype=jnp.int32), cfg),)
+    return out
 
 
 def decode_stage_bounds(cfg: ModelConfig, num_stages: int) -> list:
@@ -581,8 +650,8 @@ def decode_stage_bounds(cfg: ModelConfig, num_stages: int) -> list:
 def decode_step_staged(params, cfg: ModelConfig, token: jax.Array,
                        cache: Dict[str, Any], cache_len: jax.Array, *,
                        num_stages: int,
-                       block_tables: Optional[jax.Array] = None, ctx=None
-                       ) -> Tuple[jax.Array, Dict[str, Any], jax.Array]:
+                       block_tables: Optional[jax.Array] = None, ctx=None,
+                       moe_counts: bool = False):
     """``decode_step`` with the layer stack partitioned into
     ``num_stages`` contiguous depth groups (``decode_stage_bounds``) —
     the stage-partitioned step behind pipelined decode: the execution
@@ -594,10 +663,12 @@ def decode_step_staged(params, cfg: ModelConfig, token: jax.Array,
     The per-layer math is unchanged and runs in the same order on the
     same values, so logits and the reassembled cache are bit-identical
     to the unstaged step (tests/test_multi_unit.py pins this, and the
-    conformance matrix pins greedy token identity end to end)."""
+    conformance matrix pins greedy token identity end to end), and so
+    is the expert routing ``moe_counts`` asks for."""
     if num_stages <= 1:
         return decode_step(params, cfg, token, cache, cache_len,
-                           block_tables=block_tables, ctx=ctx)
+                           block_tables=block_tables, ctx=ctx,
+                           moe_counts=moe_counts)
     params = cast_params_for_compute(params, cfg)
     x = params["embed"].astype(_dtype(cfg.dtype))[token][:, None] \
         * math.sqrt(cfg.d_model)
@@ -608,46 +679,54 @@ def decode_step_staged(params, cfg: ModelConfig, token: jax.Array,
 
     def body(x, scanned):
         slice_params, slice_cache = scanned
-        new_cs = []
+        new_cs, helds = [], []
         for i, kind in enumerate(period):
-            x, c = _layer_decode(slice_params[i], x, kind, cfg,
-                                 cache=slice_cache[i], cache_len=cache_len,
-                                 enc_out=enc_out, tables=block_tables,
-                                 ctx=ctx)
+            x, c, held = _layer_decode(
+                slice_params[i], x, kind, cfg, cache=slice_cache[i],
+                cache_len=cache_len, enc_out=enc_out, tables=block_tables,
+                ctx=ctx)
             new_cs.append(c)
-        return x, new_cs
+            helds.append(held)
+        return x, (new_cs, helds)
 
-    scan_parts = []
-    new_rem = []
+    scan_parts, held_parts = [], []
+    new_rem, rem_held = [], []
     for s in range(num_stages):
         lo, hi = cuts[s], cuts[s + 1]
         slo, shi = min(lo, n_scan), min(hi, n_scan)
         if shi > slo:
             part = (jax.tree.map(lambda a: a[slo:shi], params["scan"]),
                     jax.tree.map(lambda a: a[slo:shi], cache["scan"]))
-            x, ncs = jax.lax.scan(body, x, part)
+            x, (ncs, helds) = jax.lax.scan(body, x, part)
             scan_parts.append(ncs)
+            held_parts.append(helds)
         for i in range(max(lo - n_scan, 0), max(hi - n_scan, 0)):
-            x, c = _layer_decode(params["rem"][i], x,
-                                 cfg.remainder_kinds[i], cfg,
-                                 cache=cache["rem"][i], cache_len=cache_len,
-                                 enc_out=enc_out, tables=block_tables,
-                                 ctx=ctx)
+            x, c, held = _layer_decode(
+                params["rem"][i], x, cfg.remainder_kinds[i], cfg,
+                cache=cache["rem"][i], cache_len=cache_len, enc_out=enc_out,
+                tables=block_tables, ctx=ctx)
             new_rem.append(c)
+            rem_held.append(held)
     new_cache: Dict[str, Any] = {"scan": [], "rem": new_rem}
+    concat = partial(jax.tree.map, lambda *xs: jnp.concatenate(xs, axis=0))
+    scan_held = held_parts[0] if len(held_parts) == 1 else \
+        concat(*held_parts) if held_parts else []
     if len(scan_parts) == 1:
         new_cache["scan"] = scan_parts[0]
     elif scan_parts:
-        new_cache["scan"] = jax.tree.map(
-            lambda *xs: jnp.concatenate(xs, axis=0), *scan_parts)
+        new_cache["scan"] = concat(*scan_parts)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _head_logits(x[:, 0], params, cfg)
-    return logits, new_cache, cache_len + 1
+    out = (logits, new_cache, cache_len + 1)
+    if moe_counts:
+        out += (_routing(scan_held, rem_held,
+                                _live(cache_len).sum(dtype=jnp.int32), cfg),)
+    return out
 
 
 def prefill_extend(params, cfg: ModelConfig, tokens: jax.Array,
-                   cache: Dict[str, Any], cache_len: jax.Array, *, ctx=None
-                   ) -> Tuple[jax.Array, Dict[str, Any], jax.Array]:
+                   cache: Dict[str, Any], cache_len: jax.Array, *, ctx=None,
+                   moe_counts: bool = False):
     """Chunked prefill: run a (B, C) token chunk through the model against
     an existing decode cache, appending its K/V rows at positions
     [cache_len, cache_len + C). Compiled once per chunk shape, so a long
@@ -656,7 +735,8 @@ def prefill_extend(params, cfg: ModelConfig, tokens: jax.Array,
 
     Requires ``supports_chunked_prefill(cfg)``. Returns per-position
     logits (B, C, V) — the caller samples at the last *real* (unpadded)
-    position — plus the updated cache and cache_len + C."""
+    position — plus the updated cache and cache_len + C, and with
+    ``moe_counts`` the chunk's expert routing (``_routing``) last."""
     assert supports_chunked_prefill(cfg), cfg.name
     params = cast_params_for_compute(params, cfg)
     x = _embed_inputs(params, cfg, tokens, None)
@@ -669,27 +749,31 @@ def prefill_extend(params, cfg: ModelConfig, tokens: jax.Array,
             p = ctx.layer(p)
         x, nc = L.attn_extend(p["block"], x, cfg, kind=kind, cache=lc,
                               cache_len=cache_len)
-        if "moe" in p:
-            x, _ = M.moe_apply(p["moe"], x, cfg, ctx=ctx)
-        elif "mlp" in p:
-            x = L.mlp_apply(p["mlp"], x, cfg)
-        return x, nc
+        x, held = _serve_mlp(p, x, cfg, ctx)
+        return x, nc, held
 
+    scan_held, rem_held = [], []
     if cfg.n_periods:
         def body(x, scanned):
             slice_params, slice_cache = scanned
-            ncs = []
+            ncs, helds = [], []
             for i, kind in enumerate(period):
-                x, nc = layer(slice_params[i], x, kind, slice_cache[i])
+                x, nc, held = layer(slice_params[i], x, kind, slice_cache[i])
                 ncs.append(nc)
-            return x, ncs
-        x, ncs = jax.lax.scan(body, x, (params["scan"], cache["scan"]))
+                helds.append(held)
+            return x, (ncs, helds)
+        x, (ncs, scan_held) = jax.lax.scan(body, x, (params["scan"],
+                                                     cache["scan"]))
         new_cache["scan"] = ncs
     for i, kind in enumerate(cfg.remainder_kinds):
-        x, nc = layer(params["rem"][i], x, kind, cache["rem"][i])
+        x, nc, held = layer(params["rem"][i], x, kind, cache["rem"][i])
         new_cache["rem"].append(nc)
+        rem_held.append(held)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return _head_logits(x, params, cfg), new_cache, cache_len + c
+    out = (_head_logits(x, params, cfg), new_cache, cache_len + c)
+    if moe_counts:
+        out += (_routing(scan_held, rem_held, b * c, cfg),)
+    return out
 
 
 def paged_insert(cfg: ModelConfig, cache: Dict[str, Any],

@@ -77,7 +77,7 @@ __all__ = ["Engine", "EngineConfig", "RequestHandle"]
 
 KV_LAYOUTS = ("slotted", "paged")
 
-_cast_for_compute = jax.jit(T.cast_params_for_compute, static_argnums=1)
+_cast_for_compute = T.hold_for_serving
 
 
 @dataclass
@@ -418,9 +418,9 @@ class Engine:
     same surface for asyncio callers. ``shutdown()`` (or exiting the
     engine's ``with`` block) stops the thread.
 
-    ``params`` are the caller's master weights (fp32). The engine holds
-    them in the compute dtype (``T.cast_params_for_compute``, cast once
-    here rather than inside every prefill and decode program) and leaves
+    ``params`` are the caller's weights. The engine holds them in the
+    compute dtype (``T.hold_for_serving``: cast once here, not in every
+    program; a leaf already in it is the caller's own buffer) and leaves
     the caller's tree as it was; the ``repro_weight_bytes`` gauge reads
     the held tree's device bytes."""
 

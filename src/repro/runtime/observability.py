@@ -38,7 +38,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "Tracer", "Observability",
+    "Tracer", "Observability", "ExpertRouting",
     "TIME_BUCKETS_S", "SIZE_BUCKETS",
     "validate_chrome_trace", "parse_prometheus",
     "simulator_trace", "failover_trace",
@@ -636,6 +636,55 @@ class Observability:
         with open(path, "w") as fh:
             json.dump(trace, fh)
         return n
+
+
+class ExpertRouting:
+    """Counters of an expert model's routing, fed by its serving programs.
+
+    Each prefill, chunk or decode program reports its routing as device
+    arrays (``transformer._routing``): the pairs routed to each held
+    expert of each expert layer, and every (token, choice) pair of the
+    program. A decode step counts the rows of its live slots alone; a
+    prefill or chunk counts every row, padding included. ``add`` reads a
+    report as its program returns. That adds no wait on the device: the
+    scheduler times each phase to the device's end whenever
+    observability is on, and only then are there counters to feed.
+
+    * ``repro_moe_routed_pairs_total``: every (token, choice) pair of
+      every expert layer, wherever its expert is held;
+    * ``repro_moe_held_pairs_total``: the pairs that went to an expert
+      held here;
+    * ``repro_moe_decode_held_pairs_total``: the same, of decode steps;
+    * ``repro_moe_max_expert_rows``: per expert layer of each decode
+      step, the rows of the most-loaded held expert (its count is the
+      decode layer-steps).
+    """
+
+    def __init__(self, registry: MetricsRegistry):
+        r = registry
+        self._routed = r.counter(
+            "repro_moe_routed_pairs_total",
+            help="(token, choice) pairs routed, every expert layer")
+        self._held = r.counter(
+            "repro_moe_held_pairs_total",
+            help="routed pairs whose expert this device holds")
+        self._decode_held = r.counter(
+            "repro_moe_decode_held_pairs_total",
+            help="held pairs of decode steps")
+        self._max_rows = r.histogram(
+            "repro_moe_max_expert_rows", SIZE_BUCKETS,
+            help="rows of the most-loaded held expert, per expert layer "
+                 "of a decode step")
+
+    def add(self, report: Any, decode: bool) -> None:
+        held = report["held"].tolist()
+        n = sum(map(sum, held))
+        self._routed.inc(int(report["routed"]))
+        self._held.inc(n)
+        if decode:
+            self._decode_held.inc(n)
+            for layer in held:
+                self._max_rows.observe(max(layer))
 
 
 # ---------------------------------------------------------------------------

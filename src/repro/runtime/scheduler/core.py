@@ -51,7 +51,8 @@ from repro.runtime.policies import (BatchAdmission, EvictLatest,
                                     make_preemption, request_due_s)
 from repro.runtime.scheduler import prefill as _prefill
 from repro.runtime.scheduler.allocator import BlockAllocator
-from repro.runtime.scheduler.layouts import PagedLayout, SlottedLayout
+from repro.runtime.scheduler.layouts import (PagedLayout, SlottedLayout,
+                                             prefill_programs)
 from repro.runtime.scheduler.types import (Completion, Request, SchedEvent,
                                            SchedulerConfig, SlotFailure,
                                            _ChunkedPrefill, _Ticket,
@@ -99,8 +100,8 @@ class ContinuousScheduler:
         self.max_len = s.max_len if not s.paged else \
             -(-s.max_len // s.block_size) * s.block_size
         max_len = self.max_len
-        self._prefill_fn = jax.jit(
-            lambda p, b: T.prefill(p, cfg, b, max_len=max_len))
+        self._prefill_fn, self._extend_fn, routing = prefill_programs(
+            cfg, max_len, obs)
         # chunked prefill (gated to configs the extend path supports)
         self._chunk = s.prefill_chunk \
             if (s.prefill_chunk > 0 and T.supports_chunked_prefill(cfg)) \
@@ -109,14 +110,11 @@ class ContinuousScheduler:
             if self._chunk else max_len
         self._chunking: Optional[_ChunkedPrefill] = None
         layout_cls = PagedLayout if s.paged else SlottedLayout
-        self.layout = layout_cls(cfg, s, max_len, self._scratch_len)
+        self.layout = layout_cls(cfg, s, max_len, self._scratch_len, routing)
         # prefix sharing resumes prefill mid-prompt through the same
         # extend path chunked prefill uses (the layout re-checks config
         # support, so the flag is the effective one)
         self._prefix = getattr(self.layout, "prefix_cache", False)
-        if self._chunk or self._prefix:
-            self._extend_fn = jax.jit(
-                lambda p, tok, c, cl: T.prefill_extend(p, cfg, tok, c, cl))
         # prefill-work accounting for the serving bench: prompt tokens
         # admitted vs prompt tokens whose K/V came from a shared prefix
         self.prefill_tokens_total = 0

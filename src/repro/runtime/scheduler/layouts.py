@@ -10,11 +10,16 @@ what the execution core's ``DecodeExecutor`` pipeline charges its
 per-stage clocks for. The staged step composes the same layer ops in the
 same order, so greedy tokens stay bit-identical to the single-stage path
 (pinned by tests/test_conformance_matrix.py).
+
+Every serving program of an expert model also reports its routing
+(``moe_counts``); with observability on, an ``ExpertRouting`` folds the
+reports into the expert counters (``prefill_programs``).
 """
 from __future__ import annotations
 
 from collections import Counter
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -23,32 +28,60 @@ import numpy as np
 
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
+from repro.runtime.observability import ExpertRouting, Observability
 from repro.runtime.scheduler.allocator import BlockAllocator
 from repro.runtime.scheduler.prefix_pool import VictimCache
 from repro.runtime.scheduler.types import Request, SchedulerConfig
 
-__all__ = ["SlottedLayout", "PagedLayout", "_PagedReservation"]
+__all__ = ["SlottedLayout", "PagedLayout", "_PagedReservation",
+           "prefill_programs"]
 
 
-def _decode_fn(cfg: ModelConfig, s: SchedulerConfig, *, paged: bool):
+def _program(cfg: ModelConfig, fn, routing: Optional[ExpertRouting],
+             decode: bool = False):
+    """``fn`` compiled. An expert model's program is asked for its
+    routing as well (``moe_counts``), which goes to ``routing`` when
+    there is one; the caller gets the program's other outputs."""
+    if cfg.moe is None:
+        return jax.jit(fn)
+    prog = jax.jit(partial(fn, moe_counts=True))
+
+    def call(*args):
+        *out, report = prog(*args)
+        if routing is not None:
+            routing.add(report, decode)
+        return tuple(out)
+    return call
+
+
+def prefill_programs(cfg: ModelConfig, max_len: int,
+                     obs: Optional[Observability]):
+    """The scheduler's compiled one-shot prefill and chunked extend, and
+    the expert counters that every serving program of an expert model
+    feeds (None without experts or with observability off)."""
+    routing = ExpertRouting(obs.registry) if (
+        cfg.moe is not None and obs is not None and obs.enabled) else None
+    return (_program(cfg, lambda p, b, **kw: T.prefill(
+                p, cfg, b, max_len=max_len, **kw), routing),
+            _program(cfg, lambda p, tok, c, cl, **kw: T.prefill_extend(
+                p, cfg, tok, c, cl, **kw), routing),
+            routing)
+
+
+def _decode_fn(cfg: ModelConfig, s: SchedulerConfig, *, paged: bool,
+               routing: Optional[ExpertRouting] = None):
     """The layout's compiled decode step: whole-model by default, stage-
     partitioned when the config pipelines decode across units."""
     stages = s.decode_stages
+    step = partial(T.decode_step_staged, num_stages=stages) \
+        if stages > 1 else T.decode_step
     if paged:
-        if stages > 1:
-            return jax.jit(lambda p, tok, cache, clen, tbl:
-                           T.decode_step_staged(p, cfg, tok, cache, clen,
-                                                num_stages=stages,
-                                                block_tables=tbl))
-        return jax.jit(lambda p, tok, cache, clen, tbl:
-                       T.decode_step(p, cfg, tok, cache, clen,
-                                     block_tables=tbl))
-    if stages > 1:
-        return jax.jit(lambda p, tok, cache, clen:
-                       T.decode_step_staged(p, cfg, tok, cache, clen,
-                                            num_stages=stages))
-    return jax.jit(lambda p, tok, cache, clen:
-                   T.decode_step(p, cfg, tok, cache, clen))
+        fn = (lambda p, tok, cache, clen, tbl, **kw:
+              step(p, cfg, tok, cache, clen, block_tables=tbl, **kw))
+    else:
+        fn = (lambda p, tok, cache, clen, **kw:
+              step(p, cfg, tok, cache, clen, **kw))
+    return _program(cfg, fn, routing, decode=True)
 
 
 class SlottedLayout:
@@ -59,10 +92,10 @@ class SlottedLayout:
     paged = False
 
     def __init__(self, cfg: ModelConfig, s: SchedulerConfig, max_len: int,
-                 scratch_len: int):
+                 scratch_len: int, routing: Optional[ExpertRouting] = None):
         self.max_len = max_len
         self.cache = T.init_cache(cfg, s.max_slots, max_len)
-        self._decode = _decode_fn(cfg, s, paged=False)
+        self._decode = _decode_fn(cfg, s, paged=False, routing=routing)
         self._insert = jax.jit(self._insert_impl)
         self._insert_sliced = jax.jit(self._insert_sliced_impl)
 
@@ -164,7 +197,7 @@ class PagedLayout:
     paged = True
 
     def __init__(self, cfg: ModelConfig, s: SchedulerConfig, max_len: int,
-                 scratch_len: int):
+                 scratch_len: int, routing: Optional[ExpertRouting] = None):
         if cfg.max_cache_len:
             raise ValueError(
                 "paged KV cache is position-indexed; max_cache_len ring "
@@ -190,7 +223,7 @@ class PagedLayout:
         self._slot_blocks: Dict[int, List[int]] = {}
         self.cache = T.init_paged_cache(cfg, num_blocks, s.block_size,
                                         s.max_slots, max_len=max_len)
-        self._decode = _decode_fn(cfg, s, paged=True)
+        self._decode = _decode_fn(cfg, s, paged=True, routing=routing)
         self._insert_paged = jax.jit(
             lambda c, rc, bids, slot: T.paged_insert(
                 cfg, c, rc, bids, slot, block_size=s.block_size))

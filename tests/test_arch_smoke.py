@@ -140,3 +140,62 @@ def test_param_count_analytic_close_to_actual(arch):
     actual = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params))
     analytic = cfg.param_count()
     assert abs(actual - analytic) / actual < 0.35, (actual, analytic)
+
+
+# sums and sums of squares (float64) of the logits of a seeded qwen2
+# smoke model: forward, prefill, one decode step and one chunked extend,
+# as the serving programs gave them before q/k norm and the expert
+# routing report existed
+DENSE_PINNED = {
+    "float32": [(21.24668483667483, 1299.467671411802),
+                (16.5801381712663, 98.6563701844836),
+                (10.119386524951551, 111.02293547557547),
+                (-1.1912203576794127, 868.2960673877167)],
+    "bfloat16": [(21.47366952896118, 1300.0197832163324),
+                 (16.56807041168213, 98.93249506955999),
+                 (10.159677505493164, 111.44582874481057),
+                 (-1.0423550605773926, 868.2958383502657)],
+}
+
+
+@pytest.mark.parametrize("dtype", sorted(DENSE_PINNED))
+def test_qk_norm_off_leaves_dense_logits_as_they_were(dtype):
+    """With ``qk_norm`` off (every dense config) the attention block
+    holds no q/k norm leaves and the logits of every program are those
+    pinned before the option existed, bit for bit."""
+    import dataclasses
+    cfg = dataclasses.replace(get_config("qwen2_1_5b").smoke(), dtype=dtype)
+    assert not cfg.qk_norm
+    params = T.init_params(cfg, jax.random.PRNGKey(5))
+    assert "q_norm" not in params["scan"][0]["block"]
+    toks = jax.random.randint(jax.random.PRNGKey(6), (2, 12), 0,
+                              cfg.vocab_size)
+    full, _ = T.forward(params, cfg, {"tokens": toks}, train=False)
+    lg, cache, clen = T.prefill(params, cfg, {"tokens": toks[:, :11]},
+                                max_len=16)
+    lg2, _, _ = T.decode_step(params, cfg, toks[:, 11], cache, clen)
+    lg3, _, _ = T.prefill_extend(params, cfg, toks[:, :8],
+                                 T.init_cache(cfg, 2, 16),
+                                 jnp.zeros((2,), jnp.int32))
+    got = [(float(np.asarray(a, np.float64).sum()),
+            float(np.square(np.asarray(a, np.float64)).sum()))
+           for a in (full, lg, lg2, lg3)]
+    assert got == DENSE_PINNED[dtype]
+
+
+def test_qk_norm_normalises_each_head():
+    """With ``qk_norm`` on, q and k leave the projection RMS-normed over
+    each head's dims, scaled by (1 + w)."""
+    import dataclasses
+
+    from repro.models import layers as L
+    cfg = dataclasses.replace(get_config("qwen3_moe_235b_a22b").smoke())
+    assert cfg.qk_norm
+    p = L.attn_init(KEY, cfg, jnp.float32)
+    p["q_norm"] = jnp.full_like(p["q_norm"], 0.5)
+    x = jax.random.normal(KEY, (1, 3, cfg.d_model)) * 7.0
+    q, k, _ = L._qkv(p, x, cfg)
+    rms = np.sqrt(np.mean(np.square(np.asarray(q)), axis=-1))
+    np.testing.assert_allclose(rms, 1.5, rtol=1e-4)
+    rms = np.sqrt(np.mean(np.square(np.asarray(k)), axis=-1))
+    np.testing.assert_allclose(rms, 1.0, rtol=1e-4)

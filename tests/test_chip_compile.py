@@ -1,4 +1,6 @@
-"""Compile the Pallas kernels for a described (not attached) TPU v5e.
+"""Compile the Pallas kernels, and the serving expert layer's grouped
+matmul (on one chip and sharded over four), for a described (not
+attached) TPU v5e.
 
 Interpret mode on CPU runs the kernel bodies but skips the chip
 compiler's checks: block-shape tiling, VMEM limits, layouts. Here each
@@ -32,10 +34,14 @@ MAX_LEN, BLOCK = 2048, 16
 PREFILL = 512
 # recurrentgemma-9b RG-LRU width
 SCAN_B, SCAN_LEN, SCAN_WIDTH = 2, 512, 4096
+# qwen3-moe-235b-a22b: one expert rank's 8 of 128 experts of width 1536
+# at d_model 4096, top-8
+MOE_D, MOE_F, MOE_E, MOE_HELD, MOE_K = 4096, 1536, 128, 8, 8
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
+    """The four devices of a described v5e:2x2."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     log_dir = os.environ.get("TPU_LOG_DIR")
@@ -50,13 +56,18 @@ def one_chip():
     cache_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", cache_on)
     compilation_cache.reset_cache()
     if log_dir is None:
         os.environ.pop("TPU_LOG_DIR", None)
     else:
         os.environ["TPU_LOG_DIR"] = log_dir
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 @pytest.fixture(scope="module")
@@ -103,3 +114,79 @@ def test_rglru_scan_compiles_for_v5e(spec):
         spec((SCAN_B, SCAN_LEN, SCAN_WIDTH), f32),
         spec((SCAN_B, SCAN_WIDTH), f32))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("b,s", [(128, 1), (1, 256)],
+                         ids=["decode-128", "chunk-256"])
+def test_dropless_expert_layer_compiles_for_v5e(spec, b, s):
+    """The serving expert layer at qwen3-moe widths, for a decode step
+    of 128 slots and a 256-token prefill chunk: its grouped matmul lowers
+    to the TPU's ragged-dot kernels (``ragged-dot-none*``, the names its
+    ops carry in a profiler trace)."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import moe as M
+    cfg = get_config("qwen3_moe_235b_a22b")
+    cfg = dataclasses.replace(cfg, dtype="bfloat16", moe=dataclasses.replace(
+        cfg.moe, held=(0, MOE_HELD)))
+    assert (cfg.d_model, cfg.moe.d_ff_expert, cfg.moe.n_experts,
+            cfg.moe.top_k) == (MOE_D, MOE_F, MOE_E, MOE_K)
+    p = {"ln": spec((MOE_D,)), "router": spec((MOE_D, MOE_E)),
+         "w_gate": spec((MOE_HELD, MOE_D, MOE_F)),
+         "w_up": spec((MOE_HELD, MOE_D, MOE_F)),
+         "w_down": spec((MOE_HELD, MOE_F, MOE_D))}
+    text = _compile_text(lambda p, x: M.moe_serve(p, x, cfg), p,
+                         spec((b, s, MOE_D)))
+    assert "ragged-dot-none" in text and "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+def test_sharded_expert_serving_keeps_expert_parallelism(v5e_2x2, step):
+    """The mesh-sharded serving steps of qwen3-moe's smoke config (8
+    experts) on a (data 2, model 2) mesh of the described v5e:2x2: each
+    grouped matmul reads its "model" shard's 4 experts, no program
+    gathers a whole expert bank, and the shards' parts are summed
+    (``moe._sharded_part``)."""
+    import dataclasses
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.configs import get_config
+    from repro.models import transformer as T
+    from repro.runtime.trainstep import make_prefill_step, make_serve_step
+    from repro.sharding.rules import cache_shardings, params_shardings
+
+    mesh = Mesh(np.array(v5e_2x2).reshape(2, 2), ("data", "model"))
+    cfg = get_config("qwen3_moe_235b_a22b").smoke()
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe,
+                                                           n_experts=8))
+    e, d, f = cfg.moe.n_experts, cfg.d_model, cfg.moe.d_ff_expert
+
+    def placed(tree, shardings):
+        return jax.tree.map(lambda a, sh: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=sh), tree, shardings)
+
+    params = jax.eval_shape(lambda: T.init_params(cfg, jax.random.PRNGKey(0)))
+    params = placed(params, params_shardings(params, mesh))
+    rows = NamedSharding(mesh, P("data"))
+    with mesh:
+        if step == "prefill":
+            tokens = jax.ShapeDtypeStruct((8, 16), jnp.int32, sharding=rows)
+            lowered = jax.jit(make_prefill_step(cfg, max_len=32, mesh=mesh)
+                              ).lower(params, {"tokens": tokens})
+        else:
+            cache = jax.eval_shape(lambda: T.init_cache(cfg, 8, 32))
+            cache = placed(cache, cache_shardings(cache, mesh))
+            vec = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=rows)
+            lowered = jax.jit(make_serve_step(cfg, mesh=mesh)).lower(
+                params, cache, vec, vec)
+        text = lowered.compile().as_text()
+    assert "ragged-dot-none" in text and "psum" in text
+
+    def bank(n):
+        return re.compile(rf"\[(1,)?{n},({d},{f}|{f},{d})\]")
+    assert bank(e // 2).search(text)
+    assert not bank(e).search(text)

@@ -163,3 +163,28 @@ def test_engine_holds_compute_dtype_tree(arch, dtype):
     gauges = parse_prometheus(eng.metrics_text())["gauges"]
     assert gauges["repro_weight_bytes"] == held_bytes
 
+
+@pytest.mark.parametrize("param_dtype", ["bfloat16", "float32"])
+def test_engine_keeps_leaves_already_in_compute_dtype(param_dtype):
+    """A leaf already in its compute dtype (every leaf of a bf16 tree;
+    the fp32 router of an fp32 one) is served from the caller's own
+    device buffer: construction makes no second copy of it. The other
+    leaves are cast, each into a buffer of its own."""
+    cfg = dataclasses.replace(get_config("qwen3_moe_235b_a22b").smoke(),
+                              dtype="bfloat16", param_dtype=param_dtype)
+    params = T.init_params(cfg, jax.random.PRNGKey(4))
+    eng = Engine(cfg, params, EngineConfig(max_slots=2, max_len=MAX_LEN))
+    flat, _ = jax.tree_util.tree_flatten_with_path(params)
+    held = jax.tree_util.tree_leaves(eng.params)
+    same = []
+    for (path, mine), theirs in zip(flat, held):
+        name = str(getattr(path[-1], "key", ""))
+        kept = mine.dtype == theirs.dtype
+        assert kept == (param_dtype == "bfloat16" or name == "router"), name
+        assert (mine.unsafe_buffer_pointer()
+                == theirs.unsafe_buffer_pointer()) == kept, name
+        same.append(kept)
+    assert any(same) and (all(same) == (param_dtype == "bfloat16"))
+    out = eng.generate([Request(0, np.arange(9, dtype=np.int32),
+                                max_new_tokens=3)])
+    assert len(out[0].tokens) == 3
