@@ -20,6 +20,12 @@ This is the memory-bound kernel of serving: per step it streams the
 whole cache once, so the roofline term is bytes(cache)/HBM_bw — the
 Pallas win over naive XLA decode is avoiding the (B, H, S) logits
 round-trip to HBM.
+
+The paged kernel (``paged_decode_attention_pallas``, the decode step's
+attention on a TPU) reads a block pool instead: it leaves the pools in
+HBM and copies each slot's live pages into VMEM itself, many pages per
+DMA group, so it reads ``ceil(length / bs)`` pages per slot where a
+gather of the block table reads all ``nb``.
 """
 from __future__ import annotations
 
@@ -76,20 +82,133 @@ def _decode_kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref,
                     ).astype(o_ref.dtype)
 
 
-def _paged_decode_kernel(tables_ref, *refs, **kw):
-    """Block-table decode attention. The body is ``_decode_kernel``'s
-    (one page per kv step); the difference is entirely in the BlockSpec
-    index maps, which chase ``tables_ref`` (scalar-prefetched to SMEM) so
-    each kv step DMAs one *physical* pool block instead of the next
-    contiguous cache slice — dead blocks are never streamed."""
-    del tables_ref
-    _decode_kernel(*refs, **kw)
-
-
 def _state_scratch(h: int, d: int):
     return [pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, d), jnp.float32)]
+
+
+# bytes of K (and again of V) that one DMA group brings into VMEM: large
+# enough that the per-chunk softmax update is amortised over many pages,
+# small enough that a slot's last, partly dead chunk costs little compute
+CHUNK_BYTES = 256 * 1024
+# bytes of q (and again of the output) that one grid step holds in VMEM
+GROUP_BYTES = 1024 * 1024
+
+
+def chunk_pages(bs: int, hk: int, d: int, nb: int, itemsize: int) -> int:
+    """Pages per DMA group of the paged kernel, from the shapes alone."""
+    return max(1, min(nb, CHUNK_BYTES // (bs * hk * d * itemsize)))
+
+
+def group_slots(b: int, h: int, d: int, itemsize: int) -> int:
+    """Slots per grid step: the largest divisor of ``b`` whose queries
+    fit ``GROUP_BYTES``."""
+    cap = max(1, GROUP_BYTES // (h * d * itemsize))
+    return max(s for s in range(1, min(b, cap) + 1) if b % s == 0)
+
+
+def _paged_decode_kernel(lengths_ref, tables_ref, q_ref, k_hbm, v_hbm,
+                         o_ref, k_buf, v_buf, sems, m_ref, l_ref, acc_ref,
+                         *, bs: int, hk: int, g: int, nb: int, chunk: int,
+                         slots: int, scale: float):
+    """One grid step serves ``slots`` slots, one after another. Each slot
+    streams its live pages, ``ceil(length / bs)`` of them, from the pools
+    in HBM into VMEM, ``chunk`` pages per group, double-buffered: the
+    next group (of this slot, or the next slot's first) is in flight
+    while the current one computes. Pages past a slot's length are
+    neither copied nor iterated."""
+    base = pl.program_id(0) * slots
+    rows = bs * hk                                  # tile rows per page
+    span = chunk * bs                               # positions per group
+
+    def n_pages(s):
+        return jnp.minimum((lengths_ref[base + s] + bs - 1) // bs, nb)
+
+    def n_chunks(s):
+        return jnp.maximum((n_pages(s) + chunk - 1) // chunk, 1)
+
+    def copies(s, c, buf, j):
+        """The K and V copies of page ``j`` of group ``c`` of slot ``s``
+        into buffer ``buf``."""
+        blk = tables_ref[(base + s) * nb + c * chunk + j]
+        dst = pl.ds(pl.multiple_of(j * rows, rows), rows)
+        return (pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[buf, dst],
+                                      sems.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[buf, dst],
+                                      sems.at[1, buf]))
+
+    def each_live_page(s, c, fn):
+        live = jnp.minimum(n_pages(s) - c * chunk, chunk)
+        jax.lax.fori_loop(0, live, lambda j, _: fn(j), None)
+
+    def start(s, c, buf):
+        def go(j):
+            for cp in copies(s, c, buf, j):
+                cp.start()
+        each_live_page(s, c, go)
+
+    def wait(s, c, buf):
+        def go(j):
+            for cp in copies(s, c, buf, j):
+                cp.wait()
+        each_live_page(s, c, go)
+
+    # rows of a group past its slot's live pages keep what an earlier
+    # group left there; zeroed once, they are always finite, so their
+    # zero softmax weight keeps them out of the sum
+    k_buf[...] = jnp.zeros_like(k_buf)
+    v_buf[...] = jnp.zeros_like(v_buf)
+    start(0, 0, 0)
+
+    def slot_body(s, buf):
+        length = lengths_ref[base + s]
+        nc = n_chunks(s)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        q = q_ref[s]                                        # (H, D)
+
+        def chunk_body(c, buf):
+            nxt = 1 - buf
+
+            @pl.when(c + 1 < nc)
+            def _():
+                start(s, c + 1, nxt)
+
+            @pl.when(jnp.logical_and(c + 1 == nc, s + 1 < slots))
+            def _():
+                start(s + 1, 0, nxt)
+
+            wait(s, c, buf)
+            k = k_buf[buf]                                  # (chunk*rows, D)
+            v = v_buf[buf]
+            sc = jax.lax.dot_general(
+                q.astype(k.dtype), k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            col = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+            row = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 0)
+            # row r of the tile is position r // hk of kv head r % hk
+            keep = jnp.logical_and(row // g == col % hk,
+                                   col < (length - c * span) * hk)
+            sc = jnp.where(keep, sc, NEG_INF)
+            m_prev = m_ref[...]                             # (H, 1)
+            m_new = jnp.maximum(m_prev, sc.max(axis=1, keepdims=True))
+            p = jnp.exp(sc - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+            acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[...] = m_new
+            return nxt
+
+        buf = jax.lax.fori_loop(0, nc, chunk_body, buf)
+        o_ref[s] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
+        return buf
+
+    jax.lax.fori_loop(0, slots, slot_body, 0)
 
 
 def paged_decode_attention_pallas(q: jax.Array, k_pool: jax.Array,
@@ -100,40 +219,42 @@ def paged_decode_attention_pallas(q: jax.Array, k_pool: jax.Array,
     physical block per logical page; lengths: (B,) valid rows. Returns
     (B, H, D).
 
-    The kv grid dimension walks logical pages 0..nb-1; the k/v BlockSpec
-    index maps read the prefetched table to pick the physical block, so
-    the DMA stream follows the page chain. Pages at or past a sequence's
-    length are skipped via ``pl.when`` (their table entries point at the
-    null block and are never read). On TPU ``bs * Hk`` should be a
-    multiple of the sublane tile (8 for fp32, 16 for bf16)."""
+    The pools stay in HBM (``pl.ANY``); the kernel copies each slot's
+    live pages itself, ``chunk_pages`` of them per DMA group, and runs
+    online-softmax attention over each group (``_paged_decode_kernel``).
+    Nothing the size of ``B x nb x bs`` rows is written. The grid walks
+    groups of ``group_slots`` slots; the block table and the lengths are
+    scalar-prefetched to SMEM. On TPU ``bs * Hk`` should be a multiple
+    of the sublane tile (8 for fp32, 16 for bf16)."""
     b, h, d = q.shape
     n, bs, hk, _ = k_pool.shape
     nb = block_tables.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    kp = k_pool.reshape(n, bs * hk, d)
-    vp = v_pool.reshape(n, bs * hk, d)
-    kernel = functools.partial(_paged_decode_kernel, bk=bs, nk=nb,
-                               scale=scale, hk=hk, g=h // hk)
-    kv_spec = pl.BlockSpec((1, bs * hk, d),
-                           lambda ib, ip, tbl, lens: (tbl[ib, ip], 0, 0))
+    itemsize = jnp.dtype(k_pool.dtype).itemsize
+    chunk = chunk_pages(bs, hk, d, nb, itemsize)
+    slots = group_slots(b, h, d, jnp.dtype(q.dtype).itemsize)
+    kernel = functools.partial(_paged_decode_kernel, bs=bs, hk=hk,
+                               g=h // hk, nb=nb, chunk=chunk, slots=slots,
+                               scale=scale)
+    qo_spec = pl.BlockSpec((slots, h, d), lambda i, lens, tbl: (i, 0, 0))
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    buf = pltpu.VMEM((2, chunk * bs * hk, d), k_pool.dtype)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, nb),
-            in_specs=[
-                pl.BlockSpec((1, h, d),
-                             lambda ib, ip, tbl, lens: (ib, 0, 0)),
-                kv_spec, kv_spec,
-            ],
-            out_specs=pl.BlockSpec((1, h, d),
-                                   lambda ib, ip, tbl, lens: (ib, 0, 0)),
-            scratch_shapes=_state_scratch(h, d),
+            grid=(b // slots,),
+            in_specs=[qo_spec, any_spec, any_spec],
+            out_specs=qo_spec,
+            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2))]
+            + _state_scratch(h, d),
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, kp, vp)
+    )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32).reshape(-1),
+      q, k_pool.reshape(n, bs * hk, d), v_pool.reshape(n, bs * hk, d))
 
 
 def decode_attention_pallas(q: jax.Array, k_cache: jax.Array,

@@ -466,6 +466,23 @@ def paged_gather(pool: jax.Array, tables: jax.Array) -> jax.Array:
     return pool[tables].reshape(b, nb * bs, hk, d)
 
 
+def _paged_attention_xla(q, k_pool, v_pool, tables, lengths):
+    return decode_attention_xla(q, paged_gather(k_pool, tables),
+                                paged_gather(v_pool, tables), lengths)
+
+
+def paged_attention(q, k_pool, v_pool, tables, lengths):
+    """Decode attention of q (B, H, D) over the pages ``tables`` names.
+    A program lowered for a TPU runs the paged Pallas kernel, which
+    streams each slot's live pages alone; every other platform gathers
+    the whole table with XLA."""
+    from repro.kernels.decode_attention.kernel import (
+        paged_decode_attention_pallas)
+    return jax.lax.platform_dependent(
+        q, k_pool, v_pool, tables, lengths,
+        tpu=paged_decode_attention_pallas, default=_paged_attention_xla)
+
+
 def attn_decode_paged(p, x, cfg, *, k_pool, v_pool, tables, cache_len
                       ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One-token decode against a paged KV pool (global attention).
@@ -475,9 +492,8 @@ def attn_decode_paged(p, x, cfg, *, k_pool, v_pool, tables, cache_len
     point there, so their (masked, never-read) writes collide harmlessly.
     The new K/V row is scattered into block ``tables[b, cache_len // bs]``
     at offset ``cache_len % bs`` (the scheduler allocates that block
-    before the step), then the slot's pages are streamed back — via the
-    block-table-aware Pallas kernel when ``attn_impl == "pallas"``, or an
-    XLA gather otherwise. Returns (out, new_k_pool, new_v_pool)."""
+    before the step), then attention reads the slot's pages
+    (``paged_attention``). Returns (out, new_k_pool, new_v_pool)."""
     h = rms_norm(x, p["ln"], cfg.norm_eps)
     positions = cache_len[:, None]
     q, k, v = _qkv(p, h, cfg)
@@ -489,13 +505,7 @@ def attn_decode_paged(p, x, cfg, *, k_pool, v_pool, tables, cache_len
     off = cache_len % bs
     kp = k_pool.at[blk, off].set(k[:, 0])
     vp = v_pool.at[blk, off].set(v[:, 0])
-    valid = cache_len + 1
-    if cfg.attn_impl == "pallas":
-        from repro.kernels.decode_attention import ops as da_ops
-        o = da_ops.paged_decode_attention(q[:, 0], kp, vp, tables, valid)
-    else:
-        o = decode_attention_xla(q[:, 0], paged_gather(kp, tables),
-                                 paged_gather(vp, tables), valid)
+    o = paged_attention(q[:, 0], kp, vp, tables, cache_len + 1)
     out = x + jnp.einsum("bhk,hkd->bd", o, p["wo"])[:, None]
     return out, kp, vp
 

@@ -687,6 +687,31 @@ class ExpertRouting:
                 self._max_rows.observe(max(layer))
 
 
+class PagedKVReads:
+    """Counters of the K/V pages a paged decode step's attention reads,
+    fed by ``PagedLayout.decode`` once per step (not per layer) from the
+    host's lengths, so they add no wait on the device.
+
+    * ``repro_paged_kv_pages_read_total``: the pages the paged kernel
+      streams, ``ceil((cache_len + 1) / block_size)`` of every slot (a
+      free slot reads its one null page);
+    * ``repro_paged_kv_table_pages_total``: every page of every slot's
+      table, what a gather of the whole table reads.
+    """
+
+    def __init__(self, registry: MetricsRegistry):
+        self._read = registry.counter(
+            "repro_paged_kv_pages_read_total",
+            help="K/V pages read by paged decode attention, per step")
+        self._table = registry.counter(
+            "repro_paged_kv_table_pages_total",
+            help="pages of every slot's block table, per decode step")
+
+    def add(self, cache_len: Any, block_size: int, table_pages: int) -> None:
+        self._read.inc(int(((cache_len + block_size) // block_size).sum()))
+        self._table.inc(table_pages)
+
+
 # ---------------------------------------------------------------------------
 # exporters for already-recorded modeled timelines
 

@@ -45,7 +45,7 @@ import numpy as np
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.runtime.observability import (SIZE_BUCKETS, TIME_BUCKETS_S,
-                                         Observability)
+                                         Observability, PagedKVReads)
 from repro.runtime.policies import (BatchAdmission, EvictLatest,
                                     FifoAdmission, Sampler, make_admission,
                                     make_preemption, request_due_s)
@@ -109,8 +109,14 @@ class ContinuousScheduler:
         self._scratch_len = -(-max_len // self._chunk) * self._chunk \
             if self._chunk else max_len
         self._chunking: Optional[_ChunkedPrefill] = None
-        layout_cls = PagedLayout if s.paged else SlottedLayout
-        self.layout = layout_cls(cfg, s, max_len, self._scratch_len, routing)
+        if s.paged:
+            kv_reads = PagedKVReads(obs.registry) if (
+                obs is not None and obs.enabled) else None
+            self.layout = PagedLayout(cfg, s, max_len, self._scratch_len,
+                                      routing, kv_reads)
+        else:
+            self.layout = SlottedLayout(cfg, s, max_len, self._scratch_len,
+                                        routing)
         # prefix sharing resumes prefill mid-prompt through the same
         # extend path chunked prefill uses (the layout re-checks config
         # support, so the flag is the effective one)
@@ -833,7 +839,7 @@ class ContinuousScheduler:
             wd = time.perf_counter()
             self._phase["kv"] = self._phase.get("kv", 0.0) + (wd - wk)
         logits = self.layout.decode(self.params, jnp.asarray(self.tokens),
-                                    jnp.asarray(self.cache_len))
+                                    self.cache_len)
         if obs is not None:
             # force the async dispatch so decode vs sampling attribution
             # is real; values are untouched, so greedy identity holds

@@ -28,7 +28,8 @@ import numpy as np
 
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
-from repro.runtime.observability import ExpertRouting, Observability
+from repro.runtime.observability import (ExpertRouting, Observability,
+                                         PagedKVReads)
 from repro.runtime.scheduler.allocator import BlockAllocator
 from repro.runtime.scheduler.prefix_pool import VictimCache
 from repro.runtime.scheduler.types import Request, SchedulerConfig
@@ -143,9 +144,9 @@ class SlottedLayout:
         self.cache = self._insert_sliced(self.cache, scratch_cache,
                                          jnp.int32(slot))
 
-    def decode(self, params, tokens: jax.Array, cache_len: jax.Array):
+    def decode(self, params, tokens: jax.Array, cache_len: np.ndarray):
         logits, self.cache, _ = self._decode(params, tokens, self.cache,
-                                             cache_len)
+                                             jnp.asarray(cache_len))
         return logits
 
     def needs_block(self, slot: int, pos: int) -> bool:
@@ -197,7 +198,8 @@ class PagedLayout:
     paged = True
 
     def __init__(self, cfg: ModelConfig, s: SchedulerConfig, max_len: int,
-                 scratch_len: int, routing: Optional[ExpertRouting] = None):
+                 scratch_len: int, routing: Optional[ExpertRouting] = None,
+                 kv_reads: Optional[PagedKVReads] = None):
         if cfg.max_cache_len:
             raise ValueError(
                 "paged KV cache is position-indexed; max_cache_len ring "
@@ -209,6 +211,7 @@ class PagedLayout:
                 "state are fixed-size per slot) — use the slotted layout; "
                 "its memory is already bounded")
         self.max_len = max_len
+        self.kv_reads = kv_reads
         self.block_size = s.block_size
         self.watermark = s.watermark
         self.pages_per_slot = max_len // s.block_size
@@ -525,9 +528,12 @@ class PagedLayout:
         return self._seed(scratch_cache, self.cache,
                           jnp.asarray(res.seed_blocks[:pages], jnp.int32))
 
-    def decode(self, params, tokens: jax.Array, cache_len: jax.Array):
+    def decode(self, params, tokens: jax.Array, cache_len: np.ndarray):
+        if self.kv_reads is not None:
+            self.kv_reads.add(cache_len, self.block_size,
+                              self.block_tables.size)
         logits, self.cache, _ = self._decode(
-            params, tokens, self.cache, cache_len,
+            params, tokens, self.cache, jnp.asarray(cache_len),
             jnp.asarray(self.block_tables))
         return logits
 

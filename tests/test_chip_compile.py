@@ -28,7 +28,7 @@ from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.rglru_scan.kernel import rglru_scan_pallas
 
 # qwen2-1.5b attention: 12 query heads over 2 kv heads of width 128,
-# an 8-slot batch at 2048 rows per slot in 16-row blocks
+# an 8-slot batch at 2048 rows per slot; paged K/V in 16-row blocks
 B, H, HK, D = 8, 12, 2, 128
 MAX_LEN, BLOCK = 2048, 16
 PREFILL = 512
@@ -82,14 +82,61 @@ def _compile_text(fn, *shapes) -> str:
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
-def test_paged_decode_attention_compiles_for_v5e(spec):
-    n_blocks = B * (MAX_LEN // BLOCK) + 1
+# the paged decode kernel at each cell's serving shape: (slots, pages
+# per slot, query heads, kv heads) of qwen2-1.5b, chatglm3-6b (4096 rows)
+# and qwen3-moe-235b-a22b (128 slots), 16-row blocks of width 128
+PAGED_SHAPES = {"qwen2-1.5b": (32, 128, 12, 2), "chatglm3-6b": (32, 256, 32, 2),
+                "qwen3-moe": (128, 128, 64, 4)}
+
+
+@pytest.mark.parametrize("cell", sorted(PAGED_SHAPES))
+def test_paged_decode_attention_compiles_for_v5e(spec, cell):
+    b, nb, h, hk = PAGED_SHAPES[cell]
+    n_blocks = b * nb + 1
     text = _compile_text(
         paged_decode_attention_pallas,
-        spec((B, H, D)), spec((n_blocks, BLOCK, HK, D)),
-        spec((n_blocks, BLOCK, HK, D)),
-        spec((B, MAX_LEN // BLOCK), jnp.int32), spec((B,), jnp.int32))
+        spec((b, h, D)), spec((n_blocks, BLOCK, hk, D)),
+        spec((n_blocks, BLOCK, hk, D)),
+        spec((b, nb), jnp.int32), spec((b,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def test_paged_decode_step_runs_the_kernel_on_a_tpu(spec, monkeypatch):
+    """A whole paged decode step of a small dense model, compiled for the
+    described chip, takes the paged kernel (``layers.paged_attention``
+    picks it by the lowering platform) and gathers no slot's whole table
+    into a (B, nb * bs, Hk, D) buffer; the same step with the gather in
+    its place does."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_config
+    from repro.models import layers as L
+    from repro.models import transformer as T
+    cfg = dataclasses.replace(get_config("qwen2_1_5b").smoke(),
+                              head_dim=D, dtype="bfloat16")
+    b, nb = 8, 4
+    params = jax.eval_shape(lambda: T.init_params(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: T.init_paged_cache(
+        cfg, b * nb + 1, BLOCK, b, max_len=nb * BLOCK))
+
+    def placed(tree):
+        return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
+    vec = spec((b,), jnp.int32)
+    args = (placed(params), vec, placed(cache), vec,
+            spec((b, nb), jnp.int32))
+
+    def step(p, tok, c, clen, tbl):
+        return T.decode_step(p, cfg, tok, c, clen, block_tables=tbl)
+    hk = cfg.n_kv_heads
+    whole = re.compile(rf"\[{b},({nb},{BLOCK}|{nb * BLOCK}),{hk},{D}\]")
+    text = _compile_text(step, *args)
+    assert "tpu_custom_call" in text
+    assert not whole.search(text)
+    monkeypatch.setattr(L, "paged_attention", L._paged_attention_xla)
+    gathered = _compile_text(lambda *a: step(*a), *args)  # a fresh trace
+    assert whole.search(gathered)
+    assert "tpu_custom_call" not in gathered
 
 
 def test_decode_attention_compiles_for_v5e(spec):

@@ -121,25 +121,53 @@ def test_decode_attention_xla_vs_ref(b, h, hk, d, s, dtype):
 
 
 PAGED_CASES = [
-    # (b, h, hk, d, num_blocks, block_size, nb_pages, dtype)
-    (2, 4, 2, 32, 9, 8, 4, jnp.float32),
-    (3, 8, 1, 64, 5, 16, 2, jnp.float32),
-    (1, 4, 4, 128, 17, 8, 8, jnp.bfloat16),
-    (4, 8, 2, 64, 13, 16, 3, jnp.float32),
+    # (b, h, hk, d, num_blocks, block_size, nb_pages, dtype, layout)
+    (2, 4, 2, 32, 9, 8, 4, jnp.float32, "random"),
+    (3, 8, 1, 64, 5, 16, 2, jnp.float32, "random"),
+    (1, 4, 4, 128, 17, 8, 8, jnp.bfloat16, "random"),
+    (4, 8, 2, 64, 13, 16, 3, jnp.float32, "random"),
+    # the served head shapes (qwen2-1.5b, chatglm3-6b, qwen3-moe) at
+    # 16-row blocks, over ragged lengths: the kernel's page groups hold
+    # 32, 32 and 16 pages (``chunk_pages``), so each slot spans two
+    (4, 12, 2, 128, 161, 16, 40, jnp.bfloat16, "ragged"),
+    (4, 32, 2, 128, 161, 16, 40, jnp.bfloat16, "ragged"),
+    (4, 64, 4, 128, 97, 16, 24, jnp.bfloat16, "ragged"),
+    # slots 0 and 1 share their first pages; groups of 16 pages do not
+    # divide the 20 of a table
+    (3, 12, 2, 128, 61, 16, 20, jnp.float32, "shared"),
 ]
 
 
-@pytest.mark.parametrize("b,h,hk,d,n,bs,nb,dtype", PAGED_CASES)
-def test_paged_decode_attention_pallas_vs_ref(b, h, hk, d, n, bs, nb, dtype):
-    """Block-table gather path: the kernel must stream exactly the pages
-    named by the table (including repeated/null physical blocks) and mask
-    rows past each sequence's length."""
+def _paged_inputs(b, h, hk, d, n, bs, nb, dtype, layout):
+    """q, pools, tables and lengths of one case. ``ragged`` lengths are
+    1 (a free slot), a page boundary, one row past it, and the whole
+    table; ``shared`` maps the first pages of slots 0 and 1 to the same
+    physical blocks (a shared prefix)."""
+    from repro.kernels.decode_attention.kernel import chunk_pages
     ks = jax.random.split(KEY, 5)
     q = jax.random.normal(ks[0], (b, h, d), dtype)
     kp = jax.random.normal(ks[1], (n, bs, hk, d), dtype)
     vp = jax.random.normal(ks[2], (n, bs, hk, d), dtype)
     tables = jax.random.randint(ks[3], (b, nb), 0, n)
     lens = jax.random.randint(ks[4], (b,), 1, nb * bs + 1)
+    if layout == "ragged":
+        edge = bs * chunk_pages(bs, hk, d, nb, jnp.dtype(dtype).itemsize)
+        assert edge < nb * bs
+        lens = jnp.array([1, edge, edge + 1, nb * bs][:b])
+    elif layout == "shared":
+        tables = tables.at[1, :nb // 2].set(tables[0, :nb // 2])
+        lens = jnp.maximum(lens, bs * (nb // 2) + 1)
+    return q, kp, vp, tables, lens
+
+
+@pytest.mark.parametrize("b,h,hk,d,n,bs,nb,dtype,layout", PAGED_CASES)
+def test_paged_decode_attention_pallas_vs_ref(b, h, hk, d, n, bs, nb, dtype,
+                                              layout):
+    """Block-table gather path: the kernel must stream exactly the pages
+    named by the table (including repeated/null physical blocks) and mask
+    rows past each sequence's length."""
+    q, kp, vp, tables, lens = _paged_inputs(b, h, hk, d, n, bs, nb, dtype,
+                                            layout)
     out = paged_decode_attention(q, kp, vp, tables, lens)
     ref = paged_decode_attention_ref(q, kp, vp, tables, lens)
     tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
@@ -148,17 +176,13 @@ def test_paged_decode_attention_pallas_vs_ref(b, h, hk, d, n, bs, nb, dtype):
                                rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("b,h,hk,d,n,bs,nb,dtype", PAGED_CASES)
-def test_paged_gather_xla_vs_ref(b, h, hk, d, n, bs, nb, dtype):
-    """The scheduler's XLA fallback (gather pages to a contiguous view,
-    then dense decode attention) equals the paged oracle."""
+@pytest.mark.parametrize("b,h,hk,d,n,bs,nb,dtype,layout", PAGED_CASES)
+def test_paged_gather_xla_vs_ref(b, h, hk, d, n, bs, nb, dtype, layout):
+    """The XLA path off the TPU (gather pages to a contiguous view, then
+    dense decode attention) equals the paged oracle."""
     from repro.models.layers import paged_gather
-    ks = jax.random.split(KEY, 5)
-    q = jax.random.normal(ks[0], (b, h, d), dtype)
-    kp = jax.random.normal(ks[1], (n, bs, hk, d), dtype)
-    vp = jax.random.normal(ks[2], (n, bs, hk, d), dtype)
-    tables = jax.random.randint(ks[3], (b, nb), 0, n)
-    lens = jax.random.randint(ks[4], (b,), 1, nb * bs + 1)
+    q, kp, vp, tables, lens = _paged_inputs(b, h, hk, d, n, bs, nb, dtype,
+                                            layout)
     out = decode_attention_xla(q, paged_gather(kp, tables),
                                paged_gather(vp, tables), lens)
     ref = paged_decode_attention_ref(q, kp, vp, tables, lens)
