@@ -12,11 +12,6 @@ policy-based ``Engine`` under different ``EngineConfig``s:
 * ``priority``     — same scheduler, priority admission: measured on the
   same Poisson trace with a contended slot budget, asserting that
   high-priority requests beat their FIFO TTFT p99 (they jump the queue);
-* ``disaggregated`` — the multi-unit execution core: the same
-  closed-loop trace through a single-unit, a 2-unit prefill/decode
-  split, and a 3-unit pipelined-decode topology on modeled per-unit
-  clocks, asserting bit-identical tokens and a >= 1.3x modeled-makespan
-  improvement for the 2-unit split;
 * ``continuous+pipelined`` — the Edge-PRUNE angle: prefill partitioned
   across two processing units via a StagedProgram, frames streamed
   through the stage pipeline with modeled per-unit clocks (paper
@@ -330,61 +325,6 @@ def _victim_rows(cfg, params, *, max_len: int, slots: int, n: int,
     ]
 
 
-def _disagg_rows(cfg, params, *, tiny: bool) -> List[Row]:
-    """Prefill/decode disaggregation on the multi-unit execution core:
-    one closed-loop trace through three unit topologies — single unit
-    (the degenerate case: modeled makespan == the sequential work sum),
-    a 2-unit prefill/decode split, and a 3-unit split with 2 pipelined
-    decode stages. Tokens must be bit-identical across all three (unit
-    topologies move modeled time, never content); the headline gate is
-    the 2-unit split beating single-unit modeled makespan by >= 1.3x.
-    The workload balances prompt and decode work and keeps the slot
-    batch small, so the dedicated prefill unit runs ahead on the next
-    admissions while the decode unit drains the current batch."""
-    n, plen, new = (6, 16, 16) if tiny else (8, 48, 48)
-    rng = np.random.RandomState(5)
-    reqs = [Request(i, rng.randint(0, cfg.vocab_size, plen).astype(np.int32),
-                    max_new_tokens=new) for i in range(n)]
-    topos = {
-        "single": dict(),
-        "disagg": dict(units=2, prefill_units=1),
-        "disagg_pipelined": dict(units=3, prefill_units=1, decode_stages=2),
-    }
-    outs, summ = {}, {}
-    for name, kw in topos.items():
-        eng = Engine(cfg, params, EngineConfig(
-            max_len=plen + new + 8, max_slots=2, **kw))
-        outs[name] = eng.generate(reqs)
-        summ[name] = eng.scheduler.core.summary()
-    for name in ("disagg", "disagg_pipelined"):
-        assert [c.tokens for c in outs[name]] == \
-            [c.tokens for c in outs["single"]], \
-            f"unit topology {name} changed greedy tokens"
-        assert summ[name]["kv_handoffs"] == n
-        # same requests -> same total modeled work on every topology
-        assert abs(summ[name]["modeled_sequential_s"]
-                   - summ["single"]["modeled_sequential_s"]) < 1e-9
-    mk = {k: v["modeled_makespan_s"] for k, v in summ.items()}
-    # single unit is the degenerate case: nothing overlaps
-    assert abs(mk["single"] - summ["single"]["modeled_sequential_s"]) < 1e-9
-    speedup = mk["single"] / mk["disagg"]
-    assert speedup >= 1.3, \
-        (f"2-unit prefill/decode split must improve modeled makespan "
-         f">= 1.3x over single-unit, got {speedup:.2f}x "
-         f"({mk['disagg']:.4f}s vs {mk['single']:.4f}s)")
-    return [
-        Row("serving", "single_unit_modeled_makespan_s", mk["single"], "s"),
-        Row("serving", "disagg_modeled_makespan_s", mk["disagg"], "s"),
-        Row("serving", "disagg_modeled_speedup", speedup, "x"),
-        Row("serving", "disagg_pipelined_modeled_makespan_s",
-            mk["disagg_pipelined"], "s"),
-        Row("serving", "disagg_pipelined_modeled_speedup",
-            mk["single"] / mk["disagg_pipelined"], "x"),
-        Row("serving", "disagg_kv_handoffs",
-            float(summ["disagg"]["kv_handoffs"]), "req"),
-    ]
-
-
 def _observability_rows(cfg, params, reqs, arrivals, *, max_len: int,
                         slots: int):
     """The same open-loop Poisson trace through an observability-enabled
@@ -477,7 +417,6 @@ def run(*, tiny: bool = False, n_requests: Optional[int] = None,
     if victim_cache:
         rows += _victim_rows(cfg, params, max_len=max_len, slots=slots,
                              n=n, max_new=new, tenants=tenants or 4)
-    rows += _disagg_rows(cfg, params, tiny=tiny)
 
     # continuous+pipelined: prefill stream through a 2-unit StagedProgram
     # on the paper's N2/i7 WiFi platform (overlapping link), modeled clocks.

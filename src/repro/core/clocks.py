@@ -1,16 +1,15 @@
 """Per-unit modeled clocks — the one scheduling recurrence everything shares.
 
-Every modeled timeline in this repo (the token-accurate ``Simulator``,
-``StagedProgram.run_pipelined``, the serving stack's multi-unit
-``ExecutionCore``) advances the same way: a piece of work on unit ``u``
-starts when its inputs are ready AND the unit is free, and occupies the
-unit until it finishes::
+Every modeled timeline in this repo (the token-accurate ``Simulator``
+and ``StagedProgram.run_pipelined``) advances the same way: a piece of
+work on unit ``u`` starts when its inputs are ready AND the unit is
+free, and occupies the unit until it finishes::
 
     start  = max(ready_s, clock[u])
     finish = start + cost_s
     clock[u] = finish
 
-``UnitClocks`` is that recurrence as an object, so the three consumers
+``UnitClocks`` is that recurrence as an object, so its consumers
 stop re-implementing it (and so their accounting — busy seconds per
 unit, makespan — agrees by construction). Units exist lazily: a unit's
 clock is 0.0 until the first charge touches it.

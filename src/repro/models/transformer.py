@@ -637,93 +637,6 @@ def decode_step(params, cfg: ModelConfig, token: jax.Array,
     return out
 
 
-def decode_stage_bounds(cfg: ModelConfig, num_stages: int) -> list:
-    """Contiguous near-even partition of the decode depth (scan periods
-    first, then remainder layers) into ``num_stages`` groups: returns
-    ``num_stages + 1`` monotone boundaries over
-    ``n_periods + len(remainder_kinds)`` depth units. A stage may be
-    empty when there are more stages than depth units."""
-    total = cfg.n_periods + len(cfg.remainder_kinds)
-    return [s * total // num_stages for s in range(num_stages + 1)]
-
-
-def decode_step_staged(params, cfg: ModelConfig, token: jax.Array,
-                       cache: Dict[str, Any], cache_len: jax.Array, *,
-                       num_stages: int,
-                       block_tables: Optional[jax.Array] = None, ctx=None,
-                       moe_counts: bool = False):
-    """``decode_step`` with the layer stack partitioned into
-    ``num_stages`` contiguous depth groups (``decode_stage_bounds``) —
-    the stage-partitioned step behind pipelined decode: the execution
-    core models stage k of one microbatch overlapping stage k−1 of the
-    next, and this function is the matching computation split. Each
-    stage runs its slice of the scanned periods (``jax.lax.scan`` over a
-    leading-axis slice of the stacked params/cache) and its remainder
-    layers; the embed feeds the first stage and the head reads the last.
-    The per-layer math is unchanged and runs in the same order on the
-    same values, so logits and the reassembled cache are bit-identical
-    to the unstaged step (tests/test_multi_unit.py pins this, and the
-    conformance matrix pins greedy token identity end to end), and so
-    is the expert routing ``moe_counts`` asks for."""
-    if num_stages <= 1:
-        return decode_step(params, cfg, token, cache, cache_len,
-                           block_tables=block_tables, ctx=ctx,
-                           moe_counts=moe_counts)
-    params = cast_params_for_compute(params, cfg)
-    x = params["embed"].astype(_dtype(cfg.dtype))[token][:, None] \
-        * math.sqrt(cfg.d_model)
-    enc_out = None
-    period = cfg.layer_pattern
-    n_scan = cfg.n_periods
-    cuts = decode_stage_bounds(cfg, num_stages)
-
-    def body(x, scanned):
-        slice_params, slice_cache = scanned
-        new_cs, helds = [], []
-        for i, kind in enumerate(period):
-            x, c, held = _layer_decode(
-                slice_params[i], x, kind, cfg, cache=slice_cache[i],
-                cache_len=cache_len, enc_out=enc_out, tables=block_tables,
-                ctx=ctx)
-            new_cs.append(c)
-            helds.append(held)
-        return x, (new_cs, helds)
-
-    scan_parts, held_parts = [], []
-    new_rem, rem_held = [], []
-    for s in range(num_stages):
-        lo, hi = cuts[s], cuts[s + 1]
-        slo, shi = min(lo, n_scan), min(hi, n_scan)
-        if shi > slo:
-            part = (jax.tree.map(lambda a: a[slo:shi], params["scan"]),
-                    jax.tree.map(lambda a: a[slo:shi], cache["scan"]))
-            x, (ncs, helds) = jax.lax.scan(body, x, part)
-            scan_parts.append(ncs)
-            held_parts.append(helds)
-        for i in range(max(lo - n_scan, 0), max(hi - n_scan, 0)):
-            x, c, held = _layer_decode(
-                params["rem"][i], x, cfg.remainder_kinds[i], cfg,
-                cache=cache["rem"][i], cache_len=cache_len, enc_out=enc_out,
-                tables=block_tables, ctx=ctx)
-            new_rem.append(c)
-            rem_held.append(held)
-    new_cache: Dict[str, Any] = {"scan": [], "rem": new_rem}
-    concat = partial(jax.tree.map, lambda *xs: jnp.concatenate(xs, axis=0))
-    scan_held = held_parts[0] if len(held_parts) == 1 else \
-        concat(*held_parts) if held_parts else []
-    if len(scan_parts) == 1:
-        new_cache["scan"] = scan_parts[0]
-    elif scan_parts:
-        new_cache["scan"] = concat(*scan_parts)
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = _head_logits(x[:, 0], params, cfg)
-    out = (logits, new_cache, cache_len + 1)
-    if moe_counts:
-        out += (_routing(scan_held, rem_held,
-                                _live(cache_len).sum(dtype=jnp.int32), cfg),)
-    return out
-
-
 def prefill_extend(params, cfg: ModelConfig, tokens: jax.Array,
                    cache: Dict[str, Any], cache_len: jax.Array, *, ctx=None,
                    moe_counts: bool = False):

@@ -119,15 +119,6 @@ class EngineConfig:
     # arrival_s + deadline_s instant passes (finish_reason="timeout")
     # instead of only ordering by deadline (EDF). Continuous only.
     enforce_deadlines: bool = False
-    # multi-unit execution core (continuous only): model the drain on
-    # `units` per-unit clocks, `prefill_units` of them dedicated to
-    # prompt prefill (0 = colocated) and the rest pipelining decode
-    # across `decode_stages` stage-partitioned groups. Token content is
-    # identical for every topology; only the modeled timeline moves.
-    units: int = 1
-    prefill_units: int = 0
-    decode_stages: int = 1
-    placement: Any = "round-robin"      # | "least-loaded" (prefill units)
     greedy: bool = True
     temperature: float = 1.0
     seed: int = 0
@@ -186,19 +177,6 @@ class EngineConfig:
                         help="admit prompts this many tokens at a time, "
                              "interleaved with decode steps (0 = one-shot "
                              "prefill)")
-        ap.add_argument("--units", type=int, default=1,
-                        help="modeled processing units for the execution "
-                             "core (1 = the classic single-unit timeline)")
-        ap.add_argument("--prefill-units", type=int, default=0,
-                        help="units dedicated to prompt prefill "
-                             "(prefill/decode disaggregation; 0 = "
-                             "colocated with decode)")
-        ap.add_argument("--decode-stages", type=int, default=1,
-                        help="decode pipeline stages across the decode "
-                             "units (stage-partitioned decode step)")
-        ap.add_argument("--placement", default="round-robin",
-                        choices=("round-robin", "least-loaded"),
-                        help="prefill-unit placement policy")
         ap.add_argument("--enforce-deadlines", action="store_true",
                         help="shed requests whose wall-clock deadline_s "
                              "passes (finish_reason='timeout') instead of "
@@ -224,10 +202,6 @@ class EngineConfig:
             prefix_cache=prefix, victim_cache=victim,
             admission=args.policy or "fifo", preemption=args.preemption,
             enforce_deadlines=args.enforce_deadlines,
-            units=getattr(args, "units", 1),
-            prefill_units=getattr(args, "prefill_units", 0),
-            decode_stages=getattr(args, "decode_stages", 1),
-            placement=getattr(args, "placement", "round-robin"),
             observability=getattr(args, "observability", False))
         kw.update(overrides)
         return cls(**kw)
@@ -491,12 +465,6 @@ class Engine:
                     "enforce_deadlines sheds on a wall clock the "
                     "static-bucket executor doesn't run; it needs a "
                     "continuous admission policy (fifo | priority | edf)")
-            if c.units != 1 or c.prefill_units or c.decode_stages != 1:
-                raise ValueError(
-                    "the multi-unit execution core charges the continuous "
-                    "scheduler's steps; batch admission runs closed "
-                    "buckets — use a continuous admission policy "
-                    "(fifo | priority | edf)")
             self.scheduler = None
             self.sampler = Sampler(greedy=c.greedy, temperature=c.temperature,
                                    seed=c.seed)
@@ -520,8 +488,6 @@ class Engine:
                     victim_eviction=c.victim_eviction,
                     prefix_cache_tenants=c.prefix_cache_tenants,
                     enforce_deadlines=c.enforce_deadlines,
-                    units=c.units, prefill_units=c.prefill_units,
-                    decode_stages=c.decode_stages, placement=c.placement,
                     step_delay_s=c.step_delay_s, debug=c.debug),
                 failures=failures, admission=self.admission,
                 preemption=self.preemption,
@@ -766,7 +732,6 @@ class Engine:
                     "active_slots": len(s.active),
                     "kv": s.kv_stats(),
                     "counters": s.stats(),
-                    "units": s.unit_stats(),
                 }
                 if getattr(s.layout, "prefix_cache", False):
                     pc = s.layout.prefix_cache_stats()

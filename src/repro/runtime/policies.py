@@ -189,43 +189,6 @@ class LowestPriority:
 
 
 # ---------------------------------------------------------------------------
-# unit-placement policies
-# ---------------------------------------------------------------------------
-# Which prefill unit takes the next prompt burst, when the execution
-# core runs dedicated prefill units (SchedulerConfig.prefill_units > 0).
-# Candidates are executor-shaped records exposing .name and .busy_s
-# (modeled busy seconds so far); .pick returns the chosen executor.
-# Like admission, placement moves *time*, never content: tokens are
-# bit-identical under any placement.
-
-
-class RoundRobinPlacement:
-    """Cycle through the prefill units in order — deterministic and
-    oblivious to load, the baseline placement."""
-
-    name = "round-robin"
-
-    def __init__(self) -> None:
-        self._next = 0
-
-    def pick(self, executors: List[Any]):
-        ex = executors[self._next % len(executors)]
-        self._next += 1
-        return ex
-
-
-class LeastLoadedPlacement:
-    """Send the burst to the prefill unit with the least modeled busy
-    time so far; ties break by unit order. Balances heterogeneous prompt
-    lengths better than round-robin."""
-
-    name = "least-loaded"
-
-    def pick(self, executors: List[Any]):
-        return min(executors, key=lambda ex: ex.busy_s)
-
-
-# ---------------------------------------------------------------------------
 # escalation policies
 # ---------------------------------------------------------------------------
 # Whether a request submitted to a TieredEngine (runtime.escalation) is
@@ -386,11 +349,6 @@ PREEMPTION_POLICIES = {
     "lowest-priority": LowestPriority,
 }
 
-PLACEMENT_POLICIES = {
-    "round-robin": RoundRobinPlacement,
-    "least-loaded": LeastLoadedPlacement,
-}
-
 VICTIM_EVICTION_POLICIES = {
     "lru": LruEviction,
     "weighted-lru": WeightedLruEviction,
@@ -426,18 +384,6 @@ def make_preemption(spec) -> Any:
             raise ValueError(
                 f"preemption policy {spec!r} not in "
                 f"{sorted(PREEMPTION_POLICIES)}") from None
-    return spec
-
-
-def make_placement(spec) -> Any:
-    """Resolve a unit-placement policy name or pass an instance through."""
-    if isinstance(spec, str):
-        try:
-            return PLACEMENT_POLICIES[spec]()
-        except KeyError:
-            raise ValueError(
-                f"placement policy {spec!r} not in "
-                f"{sorted(PLACEMENT_POLICIES)}") from None
     return spec
 
 

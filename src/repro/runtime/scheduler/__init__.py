@@ -1,7 +1,7 @@
 """Continuous-batching scheduler package.
 
 Formerly the single module ``repro.runtime.scheduler``; now split by
-concern around the multi-unit execution core:
+concern:
 
 * ``types`` — ``Request`` / ``Completion`` / ``SchedulerConfig`` /
   ``SchedEvent`` / ``SlotFailure`` and the admission-time validators;
@@ -12,8 +12,6 @@ concern around the multi-unit execution core:
   chains (tenant quotas, weighted-LRU eviction) and its checkpoint
   save/restore;
 * ``prefill`` — one-shot / prefix-resume / chunked prompt admission;
-* ``units`` — ``ExecutionCore``: unit-aware executors on modeled
-  clocks (prefill/decode disaggregation, pipelined in-flight decode);
 * ``core`` — ``ContinuousScheduler``, the loop tying them together.
 
 **Migration note:** every name the old module exported is re-exported
@@ -34,9 +32,6 @@ from repro.runtime.scheduler.types import (COUNTER_KEYS, FINISH_REASONS,
                                            SchedulerConfig, SlotFailure,
                                            _ChunkedPrefill, _Ticket,
                                            validate_request_fits)
-from repro.runtime.scheduler.units import (DecodeExecutor, ExecutionCore,
-                                           PrefillExecutor, UnitExecutor,
-                                           UnitSpec)
 
 __all__ = [
     # pre-split surface (unchanged)
@@ -44,9 +39,6 @@ __all__ = [
     "BlockAllocator", "SlottedLayout", "PagedLayout", "ContinuousScheduler",
     "sample_tokens", "validate_request_fits", "FINISH_REASONS",
     "COUNTER_KEYS",
-    # multi-unit execution core
-    "UnitSpec", "UnitExecutor", "PrefillExecutor", "DecodeExecutor",
-    "ExecutionCore",
     # prefix-cache service
     "VictimCache", "save_victim_cache", "restore_victim_cache",
 ]

@@ -1,11 +1,11 @@
 """Refcounted fixed-pool block allocator for the paged KV cache.
 
-The allocator is the ownership ledger the whole multi-unit story hangs
-off: prefill writes a slot's K/V into blocks held at refcount >= 1,
-prefix sharing maps one physical block into several tables (``share``),
-and the prefill→decode handoff is *zero-copy* precisely because the
-blocks never move — the decode units read the same pool pages the
-prefill unit wrote, and the refcount books don't change at the handoff
+The allocator is the ownership ledger of the paged cache: prefill
+writes a slot's K/V into blocks held at refcount >= 1, prefix sharing
+maps one physical block into several tables (``share``), and the
+prefill→decode handoff is *zero-copy* because the blocks never move —
+the slot joins the decode batch in place, reading the same pool pages
+its prefill wrote, and the refcount books don't change at the handoff
 (tests/test_kv_handoff_props.py pins this under arbitrary
 handoff/preemption/failure interleavings).
 """

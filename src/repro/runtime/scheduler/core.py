@@ -5,30 +5,23 @@ half lives in ``runtime.policies``; the user-facing facade is
 ``runtime.engine.Engine``). The package splits it by concern —
 ``types`` (request/config dataclasses), ``allocator`` (the refcounted
 block pool), ``layouts`` (slotted / paged KV surgery), ``prefill``
-(one-shot / prefix-resume / chunked admission compute), ``units`` (the
-multi-unit execution core) — and this module owns the loop that ties
-them together:
+(one-shot / prefix-resume / chunked admission compute) — and this
+module owns the loop that ties them together:
 
 * the decode loop — one decode function compiled ONCE at a fixed slot
   count ``max_slots``; requests join and leave the running batch between
   steps without recompiling;
 * the waiting set — *which* request is admitted next, *who* is
-  preempted under pool pressure, *how* logits become tokens, and
-  *where* prompt bursts land are the injected policies' calls;
+  preempted under pool pressure, and *how* logits become tokens are
+  the injected policies' calls;
 * the request lifecycle — per-token streaming to a ``RequestHandle``,
   cancellation, injected ``SlotFailure`` re-queue/terminate, wall-clock
-  deadline shedding, and a ``finish_reason`` on every ``Completion``;
-* the **execution core** (``units.ExecutionCore``): every prompt burst,
-  K/V handoff and batched decode step is also charged to modeled
-  per-unit clocks, giving each drain a deterministic multi-unit
-  timeline — prefill/decode disaggregation and pipelined in-flight
-  decode — without touching token content (``units=1``, the default,
-  is the degenerate case: one clock, makespan == total work).
+  deadline shedding, and a ``finish_reason`` on every ``Completion``.
 
 Per-slot ``cache_len`` makes the shared batch sound (decode attention
 masks rows at position >= cache_len[slot], so mixed-length contexts
 coexist in one step), and greedy decoding is per-request deterministic,
-so every layout/policy/unit combination emits tokens bit-identical to
+so every layout/policy combination emits tokens bit-identical to
 the static-bucket path (tests/test_conformance_matrix.py).
 """
 from __future__ import annotations
@@ -57,7 +50,6 @@ from repro.runtime.scheduler.types import (Completion, Request, SchedEvent,
                                            SchedulerConfig, SlotFailure,
                                            _ChunkedPrefill, _Ticket,
                                            validate_request_fits)
-from repro.runtime.scheduler.units import ExecutionCore
 
 __all__ = ["ContinuousScheduler"]
 
@@ -189,10 +181,6 @@ class ContinuousScheduler:
                 self._m["step_" + ph] = r.histogram(
                     f"repro_step_{ph}_seconds", TIME_BUCKETS_S,
                     help=f"per-step time inside the {ph} phase")
-        # Multi-unit execution core: every prompt burst / handoff /
-        # decode step is mirrored onto modeled per-unit clocks. Built
-        # after obs so its per-unit tracks share the tracer.
-        self.core = ExecutionCore(s, obs=self.obs)
 
     # -- legacy attribute surface (tests/benches reach for these) -----------
 
@@ -439,12 +427,6 @@ class ContinuousScheduler:
                 "prefill_tokens_total": self.prefill_tokens_total,
                 "prefill_tokens_saved": self.prefill_tokens_saved}
 
-    def unit_stats(self) -> Dict[str, Any]:
-        """The execution core's modeled multi-unit timeline: unit roster,
-        per-unit busy seconds, makespan, and the speedup over serializing
-        the same work on one unit (serving bench / snapshot surface)."""
-        return self.core.summary()
-
     # -- internals ----------------------------------------------------------
 
     def _sort_pending(self) -> None:
@@ -503,7 +485,6 @@ class ContinuousScheduler:
         self.cache_len[slot] = 0
         self.tokens[slot] = 0
         self.layout.release(slot)
-        self.core.release(slot)
         if self.obs is not None:
             # every occupied slot opened its span at admission; closing
             # here covers every exit path (finish/evict/preempt/fail/
@@ -758,8 +739,6 @@ class ContinuousScheduler:
         self.active[slot] = ticket
         # prefill -> decode handoff: the slot's K/V joins the decode
         # batch in place (zero-copy — same pool blocks, same refcounts)
-        self.core.handoff(slot, blocks=len(
-            getattr(self.layout, "_slot_blocks", {}).get(slot, ())))
         self._event(ticket.first_token_s, "admit", ticket.req.id, slot)
         if self.obs is not None:
             self._m["ttft"].observe(ticket.first_token_s - ticket.arrival_s)
@@ -853,9 +832,6 @@ class ContinuousScheduler:
                 self._phase.get("sampling", 0.0) + (now_s - ws)
             now_s -= t0
         self.step_count += 1
-        # mirror the batched step onto the modeled decode pipeline
-        # (sorted: lane membership must not depend on dict order)
-        self.core.decode_step(sorted(self.active))
         for slot in self.active:     # free slots keep cache_len == 0
             self.cache_len[slot] += 1
         for slot, ticket in list(self.active.items()):

@@ -3,13 +3,7 @@
 ``SlottedLayout`` gives every slot dense ``max_len`` rows;
 ``PagedLayout`` pools global-attention K/V behind per-slot block tables
 (allocator-backed, prefix-sharing, copy-on-write). Both compile their
-decode step once — and when ``SchedulerConfig.decode_stages > 1`` they
-compile the *stage-partitioned* decode step
-(``transformer.decode_step_staged``), whose contiguous layer groups are
-what the execution core's ``DecodeExecutor`` pipeline charges its
-per-stage clocks for. The staged step composes the same layer ops in the
-same order, so greedy tokens stay bit-identical to the single-stage path
-(pinned by tests/test_conformance_matrix.py).
+decode step once.
 
 Every serving program of an expert model also reports its routing
 (``moe_counts``); with observability on, an ``ExpertRouting`` folds the
@@ -69,19 +63,15 @@ def prefill_programs(cfg: ModelConfig, max_len: int,
             routing)
 
 
-def _decode_fn(cfg: ModelConfig, s: SchedulerConfig, *, paged: bool,
+def _decode_fn(cfg: ModelConfig, *, paged: bool,
                routing: Optional[ExpertRouting] = None):
-    """The layout's compiled decode step: whole-model by default, stage-
-    partitioned when the config pipelines decode across units."""
-    stages = s.decode_stages
-    step = partial(T.decode_step_staged, num_stages=stages) \
-        if stages > 1 else T.decode_step
+    """The layout's compiled decode step."""
     if paged:
         fn = (lambda p, tok, cache, clen, tbl, **kw:
-              step(p, cfg, tok, cache, clen, block_tables=tbl, **kw))
+              T.decode_step(p, cfg, tok, cache, clen, block_tables=tbl, **kw))
     else:
         fn = (lambda p, tok, cache, clen, **kw:
-              step(p, cfg, tok, cache, clen, **kw))
+              T.decode_step(p, cfg, tok, cache, clen, **kw))
     return _program(cfg, fn, routing, decode=True)
 
 
@@ -96,7 +86,7 @@ class SlottedLayout:
                  scratch_len: int, routing: Optional[ExpertRouting] = None):
         self.max_len = max_len
         self.cache = T.init_cache(cfg, s.max_slots, max_len)
-        self._decode = _decode_fn(cfg, s, paged=False, routing=routing)
+        self._decode = _decode_fn(cfg, paged=False, routing=routing)
         self._insert = jax.jit(self._insert_impl)
         self._insert_sliced = jax.jit(self._insert_sliced_impl)
 
@@ -226,7 +216,7 @@ class PagedLayout:
         self._slot_blocks: Dict[int, List[int]] = {}
         self.cache = T.init_paged_cache(cfg, num_blocks, s.block_size,
                                         s.max_slots, max_len=max_len)
-        self._decode = _decode_fn(cfg, s, paged=True, routing=routing)
+        self._decode = _decode_fn(cfg, paged=True, routing=routing)
         self._insert_paged = jax.jit(
             lambda c, rc, bids, slot: T.paged_insert(
                 cfg, c, rc, bids, slot, block_size=s.block_size))

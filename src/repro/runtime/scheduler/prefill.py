@@ -2,10 +2,7 @@
 
 Free functions over the ``ContinuousScheduler`` (they are the prefill
 half of its admission machinery, split out so the core loop module stays
-within the runtime module-size budget). Every compute burst here is also
-charged to the execution core's placement-chosen prefill unit
-(``sched.core.prefill``) — the modeled clock side of prefill/decode
-disaggregation; the real compute below is what the clocks model.
+within the runtime module-size budget).
 """
 from __future__ import annotations
 
@@ -36,7 +33,6 @@ def admit_one_shot(sched, ticket: _Ticket, slot: int, t0: float) -> None:
         sched.layout.register_prefix(slot, r.prompt)
     dt = time.perf_counter() - tp
     ticket.prefill_s += dt
-    sched.core.prefill(slot, len(r.prompt))
     if sched.obs is not None:
         sched._obs_prefill(slot, "prefill", tp, dt, len(r.prompt))
     first = int(sched.sampler(logits)[0])
@@ -66,7 +62,6 @@ def admit_prefix_resume(sched, ticket: _Ticket, slot: int, res,
     sched.layout.register_prefix(slot, r.prompt)
     dt = time.perf_counter() - tp
     ticket.prefill_s += dt
-    sched.core.prefill(slot, len(r.prompt) - matched)
     if sched.obs is not None:
         sched._obs_prefill(slot, "prefill (prefix resume)", tp, dt,
                            len(r.prompt) - matched)
@@ -94,7 +89,6 @@ def advance_chunked(sched, t0: float) -> None:
         jnp.full((1,), st.pos, jnp.int32)))
     dt = time.perf_counter() - tp
     st.ticket.prefill_s += dt
-    sched.core.prefill(st.slot, real, label="prefill chunk")
     if sched.obs is not None:
         sched._obs_prefill(st.slot, "prefill chunk", tp, dt, real)
     st.pos += real

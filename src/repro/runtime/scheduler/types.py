@@ -1,9 +1,9 @@
 """Request/completion/config dataclasses shared across the scheduler package.
 
 Everything here is plain data: the request the user submits, the
-completion they get back, the scheduler's configuration (including the
-multi-unit execution-core knobs), the observable event log entries, and
-the internal ticket/chunked-prefill bookkeeping records. No jax.
+completion they get back, the scheduler's configuration, the observable
+event log entries, and the internal ticket/chunked-prefill bookkeeping
+records. No jax.
 """
 from __future__ import annotations
 
@@ -150,28 +150,6 @@ class SchedulerConfig:
     # mid-decode — completing with finish_reason="timeout" and never
     # emitting another token. Requests without a deadline are untouched.
     enforce_deadlines: bool = False
-    # --- multi-unit execution core (modeled per-unit clocks) -----------
-    # units: processing units the execution core schedules over.
-    # prefill_units of them are dedicated to chunked/one-shot prefill
-    # (0 = prefill shares the decode units — the classic colocated
-    # setup); the remaining units run decode, with decode microbatches
-    # pipelined across decode_stages stage-partitioned units (1 = whole-
-    # model decode steps). The clocks are MODELED: token content is
-    # bit-identical to the single-unit path in every configuration —
-    # units=1/prefill_units=0/decode_stages=1 is the degenerate case
-    # with pure accounting and no behavior change at all.
-    units: int = 1
-    prefill_units: int = 0
-    decode_stages: int = 1
-    # which prefill unit takes the next prompt burst: a name from
-    # policies.PLACEMENT_POLICIES ("round-robin" | "least-loaded") or a
-    # policy instance
-    placement: Any = "round-robin"
-    # deterministic modeled cost per prompt/decode token on one unit —
-    # what the per-unit clocks charge (benches compare makespans across
-    # unit topologies, so costs must not depend on wall-clock noise)
-    prefill_sec_per_token: float = 1e-4
-    decode_sec_per_token: float = 1e-4
     # wall-clock device-speed handicap: sleep this long after every
     # non-idle step. Emulates serving on a slower device (an edge
     # endpoint tier vs a server tier sharing one host, as in the

@@ -11,10 +11,11 @@ locally).
 
 Gives every test's call phase a time limit of its own (standard library
 only): ``SIGALRM`` fails a test that outlives it, and ``faulthandler``
-dumps every thread's stack to the real stderr at the same moment, so a
-hang inside a C call still leaves a stack. A hang then costs one failed
-test, not the whole run. The default is ``DEFAULT_TIME_LIMIT_S``;
-``@pytest.mark.time_limit(seconds)`` sets another for one test.
+dumps every thread's stack to the real stderr ``DUMP_GRACE_S`` later if
+the test has still not returned, so a hang inside a C call still leaves
+a stack. A hang then costs one failed test, not the whole run. The
+default is ``DEFAULT_TIME_LIMIT_S``; ``@pytest.mark.time_limit(seconds)``
+sets another for one test.
 """
 from __future__ import annotations
 
@@ -42,6 +43,11 @@ else:
 
 # ~20x the slowest test of the suite on a CPU host
 DEFAULT_TIME_LIMIT_S = 300.0
+
+# the stack dump waits this long past the limit, so that a test the alarm
+# ends cancels it first: faulthandler reading the frames of a thread that
+# is running Python code can crash the process
+DUMP_GRACE_S = 10.0
 
 _stderr_fd = -1
 
@@ -73,7 +79,8 @@ def pytest_runtest_call(item):
             f"{item.nodeid} exceeded its time limit of {limit:g} s")
 
     previous = signal.signal(signal.SIGALRM, expired)
-    faulthandler.dump_traceback_later(limit, file=_stderr_fd)
+    faulthandler.dump_traceback_later(limit + DUMP_GRACE_S,
+                                      file=_stderr_fd)
     signal.setitimer(signal.ITIMER_REAL, limit)
     try:
         return (yield)

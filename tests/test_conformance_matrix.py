@@ -5,13 +5,13 @@ One differential suite pins the stack's core contract in one place
 through test_scheduler.py and test_engine_lifecycle.py): under greedy
 sampling, EVERY serving configuration —
 
-    {slotted, slotted+chunked-prefill, paged, paged+chunked-prefill,
-     paged+prefix-cache, paged+chunked+prefix, paged+prefix+victim,
-     disaggregated (dedicated prefill unit + 2 decode stages),
-     pipelined-decode (stage-partitioned decode step)}
+    {slotted, slotted+chunked-prefill, paged, paged+watermark,
+     paged+chunked-prefill, paged+prefix-cache, paged+chunked+prefix,
+     paged+prefix+victim, paged+chunked+prefix+victim}
   x {fifo, priority, deadline-EDF, batch}
   x {evict-latest, lowest-priority}
-  x 2 model configs (scan-only depth, and scan+remainder depth)
+  x 3 model configs (scan-only depth, scan+remainder depth, and a
+    sparse-expert config on {fifo, EDF, batch} x evict-latest)
 
 — must emit tokens (and finish reasons) bit-identical to the
 static-bucket oracle. Policies move waiting time, never content; cache
@@ -28,6 +28,7 @@ only a representative diagonal runs in the fast CI lane; the rest is
 """
 from __future__ import annotations
 
+import dataclasses
 import itertools
 
 import jax
@@ -38,6 +39,8 @@ from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.runtime.engine import Engine, EngineConfig
 from repro.runtime.scheduler import Request
+
+from test_moe import _tiny_qwen3
 
 KEY = jax.random.PRNGKey(0)
 
@@ -54,12 +57,19 @@ CONFIGS = {
         n_heads=4, n_kv_heads=2, d_ff=96, vocab_size=96, dtype="float32",
         param_dtype="float32", attn_chunk=16, remat=False,
         layer_pattern=("attn", "attn"), tie_embeddings=True),
+    # sparse experts shaped like the benchmark's moe_gqa family: q/k-
+    # normed GQA, 8 experts top-2, this device holding experts 2-5
+    "moe": dataclasses.replace(_tiny_qwen3(held=(2, 4)), attn_chunk=16,
+                               remat=False),
 }
 
 LAYOUTS = {
     "slotted": dict(kv_layout="slotted"),
     "slotted-chunked": dict(kv_layout="slotted", prefill_chunk=4),
     "paged": dict(kv_layout="paged", block_size=8, num_blocks=18),
+    # admission keeps 2 blocks free beyond the prompt's need
+    "paged-watermark": dict(kv_layout="paged", block_size=8, num_blocks=18,
+                            watermark=2),
     "paged-chunked": dict(kv_layout="paged", block_size=8, num_blocks=18,
                           prefill_chunk=4),
     "paged-prefix": dict(kv_layout="paged", block_size=8, num_blocks=18,
@@ -74,23 +84,18 @@ LAYOUTS = {
     "paged-prefix-victim": dict(kv_layout="paged", block_size=8,
                                 num_blocks=18, prefix_cache=True,
                                 victim_cache=True),
-    # multi-unit execution core: prefill/decode disaggregation (one
-    # dedicated prefill unit, two decode stages) over the full paged +
-    # chunked feature load, and pipelined stage-partitioned decode on
-    # the slotted layout. Placement/units move modeled time only —
-    # tokens must stay oracle-identical.
-    "disagg": dict(kv_layout="paged", block_size=8, num_blocks=18,
-                   prefill_chunk=4, units=3, prefill_units=1,
-                   decode_stages=2, placement="least-loaded"),
-    "pipelined-decode": dict(kv_layout="slotted", units=2,
-                             prefill_units=0, decode_stages=2),
+    # chunked prefill over the victim cache
+    "paged-chunked-prefix-victim": dict(kv_layout="paged", block_size=8,
+                                        num_blocks=18, prefill_chunk=4,
+                                        prefix_cache=True,
+                                        victim_cache=True),
 }
 
 ADMISSIONS = ("fifo", "priority", "edf", "batch")
 PREEMPTIONS = ("evict-latest", "lowest-priority")
 
 # the fast-lane diagonal: every layout, every admission and both
-# preemption policies appear at least once on each model config
+# preemption policies appear at least once on each dense model config
 FAST = {
     ("scan", "slotted", "batch", "evict-latest"),
     ("scan", "slotted", "fifo", "evict-latest"),
@@ -109,10 +114,10 @@ FAST = {
     ("rem", "paged-chunked-prefix", "fifo", "evict-latest"),
     ("scan", "paged-prefix-victim", "fifo", "evict-latest"),
     ("rem", "paged-prefix-victim", "priority", "lowest-priority"),
-    ("scan", "disagg", "fifo", "evict-latest"),
-    ("rem", "disagg", "priority", "lowest-priority"),
-    ("scan", "pipelined-decode", "fifo", "evict-latest"),
-    ("rem", "pipelined-decode", "edf", "evict-latest"),
+    ("scan", "paged-watermark", "priority", "lowest-priority"),
+    ("rem", "paged-chunked-prefix-victim", "edf", "evict-latest"),
+    ("moe", "slotted-chunked", "edf", "evict-latest"),
+    ("moe", "paged-chunked-prefix-victim", "fifo", "evict-latest"),
 }
 
 
@@ -125,6 +130,8 @@ def _cells():
                 and pre != "evict-latest":
             continue        # no pool -> preemption never engages; one
             #                 representative per slotted cell is enough
+        if cfg == "moe" and (adm == "priority" or pre != "evict-latest"):
+            continue        # the dense configs cover those axes
         marks = () if (cfg, lay, adm, pre) in FAST else (pytest.mark.slow,)
         yield pytest.param(cfg, lay, adm, pre,
                            id=f"{cfg}-{lay}-{adm}-{pre}", marks=marks)

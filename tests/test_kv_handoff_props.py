@@ -1,28 +1,16 @@
 """Property-based prefill→decode handoff accounting (hypothesis).
 
-The multi-unit execution core's contract, stated as properties:
-
 * the prefill→decode **handoff is zero-copy bookkeeping**: across ANY
   interleaving of admissions, chunked prefills, growth preemptions
-  (tight pool), and ``SlotFailure`` injections on a disaggregated
-  topology (dedicated prefill unit + pipelined decode stages), the
+  (tight pool), and ``SlotFailure`` injections, the
   ``BlockAllocator``'s books still balance — no block leaks or
-  double-frees just because K/V crossed a unit boundary, every request
+  double-frees when a slot's K/V joins the decode batch, every request
   gets its full token budget, and the drained pool is whole;
-* handoffs are counted exactly once per admission (one-shot, prefix
-  tail, chunked finish, and re-admission after preemption/failure all
-  included), and no slot's modeled ready time survives the drain;
-* unit topologies move **modeled time only**: the token streams are
-  bit-identical to a clean single-unit, failure-free run of the same
-  requests;
-* at the ``ExecutionCore`` level, ANY op interleaving keeps the clock
-  accounting exact: per-unit busy time sums to the sequential work,
-  the makespan never exceeds it, and ``release`` always clears a
-  slot's pending ready time.
+* preemption and failure re-queues move **time only**: the token
+  streams are bit-identical to a clean, roomy, failure-free run of the
+  same requests.
 """
 from __future__ import annotations
-
-import math
 
 import jax
 import numpy as np
@@ -30,8 +18,8 @@ import pytest
 
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
-from repro.runtime.scheduler import (ContinuousScheduler, ExecutionCore,
-                                     Request, SchedulerConfig, SlotFailure)
+from repro.runtime.scheduler import (ContinuousScheduler, Request,
+                                     SchedulerConfig, SlotFailure)
 
 hypothesis = pytest.importorskip(
     "hypothesis", reason="property tests need hypothesis (see "
@@ -51,10 +39,8 @@ PROMPT_LENS = (4, 6, 8, 12)
 @given(data=st.data())
 def test_property_handoff_books_balance(data):
     """Random workloads + random ``SlotFailure`` injections over a tight
-    paged pool on a disaggregated 3-unit topology (1 prefill unit, 2
-    pipelined decode stages): the allocator's books balance at drain,
-    handoffs count admissions exactly, and tokens match a clean
-    single-unit run bit for bit."""
+    paged pool: the allocator's books balance at drain, and tokens match
+    a clean, roomy run bit for bit."""
     rng = np.random.RandomState(data.draw(st.integers(0, 2 ** 16),
                                           label="seed"))
     n_req = data.draw(st.integers(2, 6), label="n_req")
@@ -62,8 +48,6 @@ def test_property_handoff_books_balance(data):
     # worst case: 12 prompt + 6 new tokens - 1 -> 17 rows -> 5 blocks of
     # 4; a tight pool forces growth preemption with 2-3 slots busy
     num_blocks = data.draw(st.integers(6, 14), label="num_blocks")
-    placement = data.draw(st.sampled_from(["round-robin", "least-loaded"]),
-                          label="placement")
     reqs = [Request(i, rng.randint(0, CFG.vocab_size,
                                    PROMPT_LENS[i % len(PROMPT_LENS)]
                                    ).astype(np.int32),
@@ -78,9 +62,7 @@ def test_property_handoff_books_balance(data):
     sched = ContinuousScheduler(
         CFG, PARAMS, SchedulerConfig(max_slots=3, max_len=24, paged=True,
                                      block_size=4, num_blocks=num_blocks,
-                                     prefill_chunk=chunk, debug=True,
-                                     units=3, prefill_units=1,
-                                     decode_stages=2, placement=placement),
+                                     prefill_chunk=chunk, debug=True),
         failures=failures)
     for r in reqs:
         sched.submit(r)
@@ -88,21 +70,12 @@ def test_property_handoff_books_balance(data):
     assert [o.id for o in outs] == list(range(n_req)), "request dropped"
     for o, r in zip(outs, reqs):
         assert len(o.tokens) == r.max_new_tokens
-    # the pool comes home whole despite every K/V crossing units
+    # the pool comes home whole through every handoff
     sched.alloc.check()
     assert sched.alloc.in_use == 0, "leaked blocks across the handoff"
     assert sched.alloc.available == sched.alloc.capacity
     assert not sched.block_tables.any()
-    # handoff bookkeeping drains with the pool
-    core = sched.core
-    assert core.slot_ready == {}, "stale K/V-ready time survived the drain"
-    assert core.handoffs == sched.stats()["admissions"]
-    s = core.summary()
-    assert s["kv_handoffs"] == core.handoffs
-    assert s["modeled_sequential_s"] > 0
-    assert s["modeled_makespan_s"] <= s["modeled_sequential_s"] + 1e-9
-    # units move modeled time only: bit-identical to a roomy,
-    # failure-free single-unit drain of the same requests
+    # bit-identical to a roomy, failure-free drain of the same requests
     ref = ContinuousScheduler(
         CFG, PARAMS, SchedulerConfig(max_slots=3, max_len=24, paged=True,
                                      block_size=4, num_blocks=32))
@@ -112,56 +85,3 @@ def test_property_handoff_books_balance(data):
     ref_outs = ref.run()
     assert {o.id: o.tokens for o in outs} == \
         {o.id: o.tokens for o in ref_outs}
-
-
-@settings(max_examples=200, deadline=None)
-@given(data=st.data())
-def test_property_execution_core_clock_accounting(data):
-    """ANY prefill/handoff/decode/release interleaving on a random unit
-    topology keeps the modeled accounting exact: per-unit busy sums to
-    the sequential work, the makespan never exceeds it (and never moves
-    backwards), and released slots carry no ready time."""
-    units = data.draw(st.integers(1, 5), label="units")
-    prefill_units = data.draw(st.integers(0, units - 1),
-                              label="prefill_units")
-    decode_stages = data.draw(st.integers(1, units - prefill_units),
-                              label="decode_stages")
-    s = SchedulerConfig(units=units, prefill_units=prefill_units,
-                        decode_stages=decode_stages,
-                        placement=data.draw(st.sampled_from(
-                            ["round-robin", "least-loaded"]),
-                            label="placement"),
-                        prefill_sec_per_token=1e-3,
-                        decode_sec_per_token=1e-3)
-    core = ExecutionCore(s)
-    live: set = set()
-    last_makespan = 0.0
-    for _ in range(data.draw(st.integers(0, 30), label="n_ops")):
-        op = data.draw(st.sampled_from(
-            ["prefill", "handoff", "decode", "release"]), label="op")
-        slot = data.draw(st.integers(0, 3), label="slot")
-        if op == "prefill":
-            finish = core.prefill(slot, data.draw(st.integers(1, 16),
-                                                  label="tokens"))
-            assert core.slot_ready[slot] == finish
-            live.add(slot)
-        elif op == "handoff":
-            core.handoff(slot, blocks=data.draw(st.integers(0, 4),
-                                                label="blocks"))
-        elif op == "decode":
-            lanes = data.draw(st.lists(st.integers(0, 3), min_size=0,
-                                       max_size=4, unique=True),
-                              label="slots")
-            core.decode_step(sorted(lanes))
-            live -= set(lanes)          # decode consumes the ready times
-        else:
-            core.release(slot)
-            assert slot not in core.slot_ready
-            live.discard(slot)
-        assert set(core.slot_ready) <= live
-        assert math.isclose(sum(core.clocks.busy_s.values()),
-                            core.sequential_s, rel_tol=1e-9, abs_tol=1e-12)
-        assert core.makespan_s <= core.sequential_s + 1e-9
-        assert core.makespan_s >= last_makespan, "a clock moved backwards"
-        last_makespan = core.makespan_s
-    assert core.speedup >= 1.0 - 1e-9 or core.sequential_s == 0

@@ -1,49 +1,21 @@
-"""Multi-unit execution core: unit clocks, executors, placement,
-stage-partitioned decode, and the lifted pipeline synthesis.
+"""Multi-unit modeled time outside the serving stack: the unit clocks
+and the lifted pipeline synthesis.
 
-Covers the two halves of the multi-unit story separately from the
-conformance matrix (which pins end-to-end token identity):
-
-* modeled time — ``UnitClocks`` / ``ExecutionCore`` recurrences
-  (disaggregation overlaps prefill with decode, pipelined decode
-  overlaps stages, ``units=1`` degenerates to serialized work),
-  placement policies, and the scheduler/engine integration surface;
-* computation — ``decode_step_staged`` is bit-identical to
-  ``decode_step`` for every stage count, and ``synthesize``/
-  ``run_pipelined`` now accept mappings that revisit a unit
+* ``UnitClocks`` — the one scheduling recurrence that ``Simulator`` and
+  ``StagedProgram.run_pipelined`` share;
+* ``synthesize``/``run_pipelined`` accept mappings that revisit a unit
   (endpoint → server → endpoint), contending for one physical clock.
 """
 from __future__ import annotations
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import (Link, Mapping, PlatformGraph, PlatformModel,
                         ProcessingUnit, Simulator, synthesize)
 from repro.core.clocks import UnitClocks
-from repro.models import transformer as T
-from repro.models.config import ModelConfig
-from repro.runtime.engine import Engine, EngineConfig
-from repro.runtime.policies import (LeastLoadedPlacement, RoundRobinPlacement,
-                                    make_placement)
-from repro.runtime.scheduler import (ExecutionCore, Request, SchedulerConfig)
 
 from test_core_graph import chain_graph
-
-CFG = ModelConfig(
-    name="mu", arch_type="dense", n_layers=3, d_model=48, n_heads=4,
-    n_kv_heads=2, d_ff=96, vocab_size=96, dtype="float32",
-    param_dtype="float32", attn_chunk=16, remat=False,
-    layer_pattern=("attn", "attn"), tie_embeddings=True)
-
-
-def _sched(**kw):
-    base = dict(max_slots=4, max_len=32, prefill_sec_per_token=1e-3,
-                decode_sec_per_token=1e-3)
-    base.update(kw)
-    return SchedulerConfig(**base)
 
 
 # ---------------------------------------------------------------------------
@@ -69,237 +41,6 @@ class TestUnitClocks:
         c.set("u", 5.0)
         c.set("u", 3.0)
         assert c.now("u") == 5.0
-
-
-# ---------------------------------------------------------------------------
-# ExecutionCore
-# ---------------------------------------------------------------------------
-
-class TestExecutionCore:
-    def test_single_unit_degenerate(self):
-        """units=1 (every existing config): one clock, makespan == the
-        serialized work sum, speedup exactly 1."""
-        core = ExecutionCore(_sched())
-        core.prefill(0, 10)
-        core.handoff(0)
-        for _ in range(5):
-            core.decode_step([0])
-        assert core.makespan_s == pytest.approx(core.sequential_s)
-        assert core.speedup == pytest.approx(1.0)
-        assert [u.name for u in core.units] == ["decode0"]
-
-    def test_disaggregation_overlaps_prefill_with_decode(self):
-        """A dedicated prefill unit absorbs prompt bursts while the
-        decode unit streams tokens: the modeled makespan beats the
-        serialized sum."""
-        core = ExecutionCore(_sched(units=2, prefill_units=1))
-        for slot in range(4):
-            core.prefill(slot, 20)
-            core.handoff(slot)
-            active = list(range(slot + 1))
-            for _ in range(10):
-                core.decode_step(active)
-        assert core.makespan_s < core.sequential_s
-        assert core.speedup > 1.3
-        busy = core.clocks.busy_s
-        assert busy["prefill0"] > 0 and busy["decode0"] > 0
-
-    def test_prefill_chunks_chain_per_slot(self):
-        """Chunks of one slot never overlap each other even with two
-        prefill units: the slot's ready time chains them."""
-        core = ExecutionCore(_sched(units=3, prefill_units=2))
-        f1 = core.prefill(0, 10)
-        f2 = core.prefill(0, 10)          # placed round-robin on prefill1
-        assert f2 == pytest.approx(f1 + 10 * core.prefill_spt)
-
-    def test_pipelined_decode_splits_stage_cost(self):
-        """K stages each charge 1/K of the step; with one lane per stage
-        the pipeline fills and the makespan stays below K serialized
-        steps."""
-        one = ExecutionCore(_sched())
-        two = ExecutionCore(_sched(units=2, decode_stages=2))
-        slots = [0, 1, 2, 3]
-        for core in (one, two):
-            for s in slots:
-                core.prefill(s, 1)
-                core.handoff(s)
-            for _ in range(20):
-                core.decode_step(slots)
-        # same total work, overlapped stages -> strictly faster
-        assert two.sequential_s == pytest.approx(one.sequential_s)
-        assert two.makespan_s < one.makespan_s
-        assert two.speedup > 1.0
-
-    def test_handoff_is_bookkeeping_only(self):
-        core = ExecutionCore(_sched(units=2, prefill_units=1))
-        core.prefill(0, 8)
-        before = dict(core.clocks.busy_s)
-        core.handoff(0, blocks=3)
-        assert core.clocks.busy_s == before     # no time charged
-        assert core.handoffs == 1
-
-    def test_release_clears_slot_state(self):
-        core = ExecutionCore(_sched())
-        core.prefill(0, 8)
-        core.release(0)
-        assert 0 not in core.slot_ready
-
-    def test_summary_schema(self):
-        core = ExecutionCore(_sched(units=3, prefill_units=1,
-                                    decode_stages=2))
-        core.prefill(0, 4)
-        core.decode_step([0])
-        s = core.summary()
-        assert {u["role"] for u in s["units"]} == {"prefill", "decode"}
-        assert len(s["units"]) == 3
-        assert s["decode_stages"] == 2
-        assert s["modeled_makespan_s"] > 0
-        assert s["modeled_sequential_s"] >= s["modeled_makespan_s"] - 1e-12
-        assert s["kv_handoffs"] == 0
-
-    @pytest.mark.parametrize("kw,msg", [
-        (dict(units=0), "units"),
-        (dict(units=2, prefill_units=2), "prefill_units"),
-        (dict(units=2, prefill_units=1, decode_stages=2), "decode_stages"),
-    ])
-    def test_invalid_topologies_rejected(self, kw, msg):
-        with pytest.raises(ValueError, match=msg):
-            ExecutionCore(_sched(**kw))
-
-
-# ---------------------------------------------------------------------------
-# placement policies
-# ---------------------------------------------------------------------------
-
-class _FakeExec:
-    def __init__(self, name, busy):
-        self.name, self.busy_s = name, busy
-
-
-class TestPlacement:
-    def test_round_robin_cycles(self):
-        p = RoundRobinPlacement()
-        execs = [_FakeExec("a", 0.0), _FakeExec("b", 0.0)]
-        assert [p.pick(execs).name for _ in range(4)] == ["a", "b", "a", "b"]
-
-    def test_least_loaded_picks_min_busy(self):
-        p = LeastLoadedPlacement()
-        execs = [_FakeExec("a", 5.0), _FakeExec("b", 1.0)]
-        assert p.pick(execs).name == "b"
-
-    def test_factory_resolves_names(self):
-        assert isinstance(make_placement("round-robin"), RoundRobinPlacement)
-        assert isinstance(make_placement("least-loaded"), LeastLoadedPlacement)
-        with pytest.raises(ValueError, match="placement policy"):
-            make_placement("nope")
-
-
-# ---------------------------------------------------------------------------
-# stage-partitioned decode step
-# ---------------------------------------------------------------------------
-
-class TestStagedDecode:
-    @pytest.mark.parametrize("stages", [1, 2, 3])
-    def test_bit_identical_to_decode_step(self, stages):
-        params = T.init_params(CFG, jax.random.PRNGKey(0))
-        batch = {"tokens": (jnp.arange(6, dtype=jnp.int32)[None] % 17)}
-        logits, cache, clen = T.prefill(params, CFG, batch, max_len=32)
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)
-        l0, c0, n0 = T.decode_step(params, CFG, tok, cache, clen)
-        l1, c1, n1 = T.decode_step_staged(params, CFG, tok, cache, clen,
-                                          num_stages=stages)
-        np.testing.assert_array_equal(np.asarray(l0), np.asarray(l1))
-        for a, b in zip(jax.tree.leaves(c0), jax.tree.leaves(c1)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        np.testing.assert_array_equal(np.asarray(n0), np.asarray(n1))
-
-    def test_stage_bounds_cover_depth_contiguously(self):
-        total = CFG.n_periods + len(CFG.remainder_kinds)
-        for k in range(1, 5):
-            cuts = T.decode_stage_bounds(CFG, k)
-            assert cuts[0] == 0 and cuts[-1] == total
-            assert cuts == sorted(cuts)
-
-
-# ---------------------------------------------------------------------------
-# engine integration
-# ---------------------------------------------------------------------------
-
-class TestEngineIntegration:
-    def test_unit_stats_in_snapshot_and_identity(self):
-        params = T.init_params(CFG, jax.random.PRNGKey(0))
-        reqs = [Request(i, (np.arange(4 + i) % CFG.vocab_size)
-                        .astype(np.int32), max_new_tokens=4)
-                for i in range(4)]
-        ref = Engine(CFG, params, EngineConfig(
-            max_len=32, admission="batch")).generate(
-                [Request(r.id, r.prompt.copy(), max_new_tokens=4)
-                 for r in reqs])
-        eng = Engine(CFG, params, EngineConfig(
-            max_len=32, max_slots=2, units=3, prefill_units=1,
-            decode_stages=2, placement="least-loaded"))
-        outs = eng.generate(reqs)
-        assert [c.tokens for c in outs] == [c.tokens for c in ref]
-        units = eng.snapshot()["units"]
-        assert units["kv_handoffs"] == len(reqs)
-        assert units["modeled_makespan_s"] > 0
-        assert {u["name"] for u in units["units"]} == \
-            {"decode0", "decode1", "prefill0"}
-
-    def test_unit_trace_tracks_modeled_clock_only(self):
-        """With observability on, a non-trivial topology traces per-unit
-        timelines into a dedicated "units" process on the MODELED clock
-        (one thread per unit, never mixed with the engine's wall-clock
-        tracks), and the combined trace still validates. A single-unit
-        engine emits no unit track at all — its default trace stays
-        wall-clock-only (tests/test_server.py pins that)."""
-        from repro.runtime.observability import validate_chrome_trace
-        params = T.init_params(CFG, jax.random.PRNGKey(0))
-        reqs = [Request(i, (np.arange(6) % CFG.vocab_size)
-                        .astype(np.int32), max_new_tokens=3)
-                for i in range(3)]
-        eng = Engine(CFG, params, EngineConfig(
-            max_len=32, max_slots=2, units=3, prefill_units=1,
-            decode_stages=2, observability=True))
-        eng.generate(reqs)
-        trace = eng.trace_json()
-        assert validate_chrome_trace(trace) > 0
-        pids = {m["pid"]: m["args"]["name"]
-                for m in trace["traceEvents"]
-                if m.get("ph") == "M" and m.get("name") == "process_name"}
-        unit_pids = {p for p, n in pids.items() if n == "units"}
-        assert unit_pids, "no per-unit trace process"
-        ev = [e for e in trace["traceEvents"]
-              if e.get("pid") in unit_pids and e.get("ph") != "M"]
-        assert ev and {e["cat"] for e in ev} == {"modeled"}
-        names = {e["name"] for e in ev}
-        assert any(n.startswith("prefill") for n in names)
-        assert "kv-handoff" in names
-        single = Engine(CFG, params, EngineConfig(
-            max_len=32, max_slots=2, observability=True))
-        single.generate([Request(9, (np.arange(6) % CFG.vocab_size)
-                                 .astype(np.int32), max_new_tokens=3)])
-        strace = single.trace_json()
-        spids = {m["pid"] for m in strace["traceEvents"]
-                 if m.get("ph") == "M" and m.get("name") == "process_name"
-                 and m["args"]["name"] == "units"}
-        assert not spids, "single-unit engine must not open a units track"
-
-    def test_batch_admission_rejects_multi_unit(self):
-        params = T.init_params(CFG, jax.random.PRNGKey(0))
-        with pytest.raises(ValueError, match="multi-unit"):
-            Engine(CFG, params, EngineConfig(admission="batch", units=2))
-
-    def test_cli_flags_round_trip(self):
-        import argparse
-        ap = argparse.ArgumentParser()
-        EngineConfig.add_cli_args(ap)
-        args = ap.parse_args(["--units", "3", "--prefill-units", "1",
-                              "--decode-stages", "2",
-                              "--placement", "least-loaded"])
-        c = EngineConfig.from_args(args)
-        assert (c.units, c.prefill_units, c.decode_stages) == (3, 1, 2)
-        assert c.placement == "least-loaded"
 
 
 # ---------------------------------------------------------------------------

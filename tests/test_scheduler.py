@@ -118,13 +118,15 @@ def test_growth_can_preempt_inflight_chunked_prefill(setup):
     assert sched.alloc.in_use == 0
 
 
-@pytest.mark.parametrize("preemption,priorities,units", [
+@pytest.mark.parametrize("preemption,priorities,prefill", [
     ("evict-latest", (0, 0), {}),
-    ("evict-latest", (0, 0), dict(units=3, prefill_units=1,
-                                  decode_stages=2)),
+    # one chunk per prompt: smaller chunks serialize the two
+    # admissions, so the pool never runs dry
+    ("evict-latest", (0, 0), dict(prefill_chunk=4)),
     ("lowest-priority", (0, 1), {}),
-], ids=["single-unit", "disaggregated", "lowest-priority"])
-def test_growth_preemption_drain_ends(setup, preemption, priorities, units):
+], ids=["one-shot", "chunked", "lowest-priority"])
+def test_growth_preemption_drain_ends(setup, preemption, priorities,
+                                      prefill):
     """The smallest drain in which growth preemption can ping-pong: two
     4-row prompts fill one page each of a 3-block pool, and both need a
     second page on the same step. A grower may only evict a holder its
@@ -140,7 +142,7 @@ def test_growth_preemption_drain_ends(setup, preemption, priorities, units):
     sched = ContinuousScheduler(
         cfg, params, SchedulerConfig(max_slots=2, max_len=8, paged=True,
                                      block_size=4, num_blocks=4, debug=True,
-                                     **units),
+                                     **prefill),
         preemption=preemption)
     for r in reqs:
         sched.submit(r)

@@ -2,7 +2,7 @@
 compute dtype once, at construction, instead of inside every program.
 
 * every serving path (paged with chunked prefill and the prefix cache,
-  slotted, static buckets, stage-partitioned decode) gives the greedy
+  paged alone, slotted, static buckets) gives the greedy
   tokens of ``T.prefill`` / ``T.decode_step`` run on the raw fp32 tree,
   and logits bit-identical to the same engine serving the fp32 masters
   with the per-program cast;
@@ -76,7 +76,7 @@ CASES = {
                                  prefill_chunk=8, prefix_cache=True),
     "slotted": dict(kv_layout="slotted"),
     "batch": dict(admission="batch"),
-    "decode-stages-2": dict(units=2, decode_stages=2),
+    "paged": dict(kv_layout="paged", block_size=8),
 }
 
 

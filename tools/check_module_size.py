@@ -3,7 +3,7 @@
 
 The scheduler started life as one 1,700-line monolith and was split
 into ``runtime/scheduler/`` (types / allocator / layouts / prefill /
-units / core) precisely so no single module re-accretes everything.
+prefix_pool / core) precisely so no single module re-accretes everything.
 This lint is the ratchet: it fails the fast CI lane the moment any
 module under ``src/repro/runtime/`` crosses the line budget, so growth
 has to land as a new module (or a real refactor) instead of another
